@@ -10,15 +10,16 @@ import dataclasses
 
 from repro.configs import get_config
 from repro.core.kv_policy import MemoryModel
+from repro.runtime.hw import TPU_V5E
 
 ARCH = "llama3.1-8b"
 
 
 def run(emit):
     cfg = get_config(ARCH)
-    naive = MemoryModel(cfg, weight_bytes_per_param=1.0,
+    naive = MemoryModel(cfg, TPU_V5E, weight_bytes_per_param=1.0,
                         output_prealloc=False, inplace=False)
-    opt = MemoryModel(cfg, weight_bytes_per_param=1.0)
+    opt = MemoryModel(cfg, TPU_V5E, weight_bytes_per_param=1.0)
     steps = [
         ("paged_baseline", naive.max_input_length("paged")),
         ("+kv_discard", naive.max_input_length("discard")),

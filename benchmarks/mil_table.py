@@ -9,6 +9,7 @@ from __future__ import annotations
 
 from repro.configs import get_config
 from repro.core.kv_policy import MemoryModel
+from repro.runtime.hw import TPU_V5E
 
 WL1_MAX = 19_000
 WL2_MAX = 60_000
@@ -24,7 +25,7 @@ def run(emit):
     for arch, wbytes in (("llama3.1-8b", 1.0), ("llama3.1-8b", 2.0),
                          ("qwen1.5-0.5b", 2.0), ("granite-3-8b", 1.0)):
         cfg = get_config(arch)
-        mm = MemoryModel(cfg, weight_bytes_per_param=wbytes)
+        mm = MemoryModel(cfg, TPU_V5E, weight_bytes_per_param=wbytes)
         mil = mm.mil_table()
         for t in TECHS:
             wl1 = "Y" if mil[t] >= WL1_MAX else "x"

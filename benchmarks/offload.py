@@ -37,6 +37,7 @@ import numpy as np
 from repro.configs import get_config, reduce_config
 from repro.core.engine import EngineConfig, PrefillOnlyEngine
 from repro.core.kv_policy import MemoryModel
+from repro.runtime.hw import TPU_V5E
 from repro.models.model import build
 from repro.runtime.sharding import materialize
 
@@ -110,7 +111,8 @@ def run(n_requests: int):
         rows.append(row)
 
     # analytic headline on the target chip: freed HBM -> larger cache
-    mm = MemoryModel(get_config("llama3.1-8b"), weight_bytes_per_param=1)
+    mm = MemoryModel(get_config("llama3.1-8b"), TPU_V5E,
+                     weight_bytes_per_param=1)
     keep = 1024
     mil_all = mm.max_input_length("hybrid", kv_keep=1 << 30)
     cache_all = mm.prefix_budget_tokens(mil_all, kv_keep=mil_all)

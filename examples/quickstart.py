@@ -13,10 +13,12 @@ import numpy as np
 from repro.configs import get_config, reduce_config
 from repro.core.engine import EngineConfig, PrefillOnlyEngine
 from repro.models.model import build
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.runtime.sharding import materialize
 
 
 def main():
+    enable_compile_cache()
     cfg = reduce_config(get_config("qwen1.5-0.5b"))
     api = build(cfg)
     params = materialize(jax.random.PRNGKey(0), api.defs(), jnp.float32)

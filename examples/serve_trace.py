@@ -8,6 +8,7 @@ JCT-aware routing, open-loop real-time arrivals).
 import argparse
 
 from repro.launch.serve import serve_trace
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def main():
@@ -19,6 +20,7 @@ def main():
     ap.add_argument("--router", default="least_backlog",
                     choices=["user_hash", "least_backlog"])
     args = ap.parse_args()
+    enable_compile_cache()
 
     out = serve_trace("qwen1.5-0.5b", "post_recommendation", qps=args.qps,
                       n_instances=args.instances, policy=args.policy,

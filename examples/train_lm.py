@@ -10,6 +10,7 @@ expect TPU-scale hardware for a few hundred steps).
 import argparse
 
 from repro.launch.train import train
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def main():
@@ -21,6 +22,7 @@ def main():
     ap.add_argument("--ckpt-dir", default="/tmp/prefillonly_train_ck")
     ap.add_argument("--full-size", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     losses = train(args.arch, steps=args.steps, seq_len=args.seq_len,
                    global_batch=args.global_batch,

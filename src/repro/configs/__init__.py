@@ -13,4 +13,5 @@ from repro.configs.registry import (  # noqa: F401
     get_config,
     list_archs,
     reduce_config,
+    serving_config,
 )

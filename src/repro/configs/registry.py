@@ -48,6 +48,14 @@ def list_archs(assigned_only: bool = True) -> List[str]:
     return sorted(ASSIGNED if assigned_only else REGISTRY)
 
 
+def serving_config(arch: str, published_widths: bool = False) -> ModelConfig:
+    """The config an entry point serves ``arch`` with: its published widths
+    (hybrid prefilling on, as configured), or the reduced CPU preset with
+    hybrid prefilling off. The one owner of that choice."""
+    cfg = get_config(arch)
+    return cfg if published_widths else reduce_config(cfg, hybrid_chunk=0)
+
+
 def reduce_config(cfg: ModelConfig, **overrides) -> ModelConfig:
     """Shrink a config to a CPU-smoke-testable size, same family/features.
 

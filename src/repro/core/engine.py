@@ -9,9 +9,11 @@ Workflow (Figure 2):
                    block cache -> constrained single-token output (the
                    paper's P(Yes)/P(No) scoring)
 
-This engine runs REAL forwards (CPU-scale models in tests/examples; the same
-code drives a TPU instance mesh via launch/serve.py). Shapes are bucketed so
-jit compiles a bounded set of programs.
+This engine runs REAL forwards: the reduced preset on the CPU in tests, and
+published widths on a TPU chip through the same code (``launch/serve.py``,
+``chip_smoke.py``). Each engine commits its parameters to one device, so one
+process can drive one replica per chip. Shapes are bucketed so jit compiles
+a bounded set of programs.
 
 Prepacked prefill (arXiv:2404.09529 / BatchLLM arXiv:2412.03594)
 ----------------------------------------------------------------
@@ -71,6 +73,7 @@ from repro.models import transformer as tfm
 from repro.models.layers import PAD_POS
 from repro.models.model import cast_params
 from repro.runtime.fault_tolerance import NaNGuard
+from repro.runtime.hw import chip_for
 from repro.serving.tracing import BatchRecord, JCTCalibrationMonitor
 
 
@@ -127,13 +130,21 @@ class EngineConfig:
 
 
 class PrefillOnlyEngine:
-    """Single-instance engine over a dense-family model (real arrays)."""
+    """Single-instance engine over a dense-family model (real arrays).
+
+    ``device`` (default: the first device) holds this engine's parameters and
+    therefore its forwards and KV: inputs are uncommitted and follow the
+    committed parameters. ``chip`` is that device's peak table, which the KV
+    tier and admission price against."""
 
     def __init__(self, cfg: ModelConfig, params,
-                 ecfg: Optional[EngineConfig] = None):
+                 ecfg: Optional[EngineConfig] = None, device=None):
         assert cfg.family in ("dense", "vlm", "audio", "moe"), cfg.family
         self.cfg = cfg
-        self.params = cast_params(params, cfg.dtype)
+        self.device = jax.devices()[0] if device is None else device
+        self.chip = chip_for(self.device.platform, self.device.device_kind)
+        self.params = jax.device_put(cast_params(params, cfg.dtype),
+                                     self.device)
         # per-engine config: a shared default instance would alias mutable
         # state (autotune) across every engine in a pool
         self.ecfg = ecfg = EngineConfig() if ecfg is None else ecfg
@@ -154,7 +165,8 @@ class PrefillOnlyEngine:
                 ecfg.cache_capacity_tokens // ecfg.block_size,
                 ecfg.block_size,
                 host_store=HostKVStore(ecfg.host_cache_bytes), cfg=cfg,
-                policy=OffloadPolicy(host_bw=ecfg.offload_host_bw))
+                policy=OffloadPolicy(self.chip,
+                                     host_bw=ecfg.offload_host_bw))
         else:
             self.cache = PrefixCache(
                 ecfg.cache_capacity_tokens // ecfg.block_size,
@@ -246,11 +258,12 @@ class PrefillOnlyEngine:
 
     def _measure_host_bw(self, nbytes: int = 8 << 20) -> float:
         """Measured device->host->device round-trip bandwidth (bytes/s)."""
-        arr = jnp.zeros((nbytes // 4,), jnp.float32)
+        arr = jax.device_put(np.zeros((nbytes // 4,), np.float32),
+                             self.device)
         jax.block_until_ready(arr)
         t0 = time.perf_counter()
         host = np.asarray(arr)                       # device -> host
-        back = jnp.asarray(host)                     # host -> device
+        back = jax.device_put(host, self.device)     # host -> device
         jax.block_until_ready(back)
         dt = max(time.perf_counter() - t0, 1e-9)
         return 2.0 * nbytes / dt
@@ -520,7 +533,7 @@ class PrefillOnlyEngine:
         if not blocks:
             return
         # host -> device outside the lock (the copy is the slow part)
-        dev = [(h, tuple(jnp.asarray(p) for p in payload))
+        dev = [(h, tuple(jax.device_put(p, self.device) for p in payload))
                for h, payload in host_payloads]
         for _, payload in dev:
             jax.block_until_ready(payload)

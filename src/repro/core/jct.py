@@ -18,7 +18,7 @@ from typing import List, Sequence, Tuple
 import numpy as np
 
 from repro.configs.base import ModelConfig
-from repro.runtime.hw import ChipSpec, DEFAULT_CHIP
+from repro.runtime.hw import TPU_V5E, ChipSpec
 
 Sample = Tuple[int, int, float]  # (n_input, n_cached, seconds)
 
@@ -240,7 +240,7 @@ class RooflineJCT:
 
     cfg: ModelConfig
     chips: int = 1
-    chip: ChipSpec = DEFAULT_CHIP
+    chip: ChipSpec = TPU_V5E            # analytic model of the target
     efficiency: float = 0.55
     comm_bytes_per_token: float = 0.0   # TP: 2*(k-1)/k * d_model * 2L * bytes
     attn_efficiency: float = 1.0        # chunked-prefill kernel penalty < 1

@@ -23,7 +23,7 @@ import dataclasses
 from typing import Dict, Optional, Sequence, Tuple
 
 from repro.configs.base import ModelConfig
-from repro.runtime.hw import ChipSpec, DEFAULT_CHIP
+from repro.runtime.hw import ChipSpec
 
 BYTES = 2  # bf16
 
@@ -102,7 +102,7 @@ class KVLifecycle:
 @dataclasses.dataclass
 class MemoryModel:
     cfg: ModelConfig
-    chip: ChipSpec = DEFAULT_CHIP
+    chip: ChipSpec                    # the device this model prices
     utilization: float = 0.9          # HBM headroom kept for the allocator
     weight_bytes_per_param: float = BYTES  # 1.0 = fp8 (paper's quantized setups)
     # hybrid-prefilling micro-optimizations (paper §4.3): without output

@@ -26,7 +26,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.core.prefix_cache import Chain, PrefixCache
-from repro.runtime.hw import ChipSpec, DEFAULT_CHIP
+from repro.runtime.hw import ChipSpec
 
 
 def _nbytes(payload: Any) -> int:
@@ -56,14 +56,15 @@ def to_host(payload: Any) -> Any:
 class OffloadPolicy:
     """Transfer-vs-recompute break-even for the DRAM tier.
 
-    Defaults are sourced from the target ``ChipSpec`` (``runtime/hw.py``)
-    rather than re-hardcoded here; ``host_bw``/``peak_flops`` accept
-    explicit overrides (e.g. a measured PCIe bandwidth from ``profile()``).
+    Defaults are sourced from the ``ChipSpec`` of the device the engine
+    runs on (``runtime/hw.py``) rather than re-hardcoded here;
+    ``host_bw``/``peak_flops`` accept explicit overrides (e.g. a measured
+    PCIe bandwidth from ``profile()``).
     """
+    chip: ChipSpec
     host_bw: Optional[float] = None      # bytes/s device<->host
     peak_flops: Optional[float] = None   # FLOP/s
     efficiency: float = 0.5
-    chip: ChipSpec = DEFAULT_CHIP
 
     def __post_init__(self):
         if self.host_bw is None:
@@ -158,12 +159,12 @@ class TieredPrefixCache(PrefixCache):
 
     def __init__(self, capacity_blocks: int, block_size: int = 16,
                  host_store: Optional[HostKVStore] = None,
-                 cfg: Optional[ModelConfig] = None,
-                 policy: Optional[OffloadPolicy] = None):
+                 cfg: Optional[ModelConfig] = None, *,
+                 policy: OffloadPolicy):
         super().__init__(capacity_blocks, block_size)
         self.host = host_store or HostKVStore()
         self.cfg = cfg
-        self.policy = policy if policy is not None else OffloadPolicy()
+        self.policy = policy
         self.restored_blocks = 0
 
     def _remove(self, h: int):

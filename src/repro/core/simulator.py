@@ -20,7 +20,7 @@ from repro.core.kv_policy import MemoryModel
 from repro.core.prefix_cache import PrefixCache
 from repro.core.scheduler import Request, Scheduler
 from repro.configs.base import ModelConfig
-from repro.runtime.hw import ChipSpec, DEFAULT_CHIP
+from repro.runtime.hw import TPU_V5E, ChipSpec
 
 
 @dataclasses.dataclass
@@ -119,7 +119,7 @@ class _Instance:
 
 class Simulator:
     def __init__(self, cfg: ModelConfig, spec: EngineSpec, *,
-                 total_chips: int = 2, chip: ChipSpec = DEFAULT_CHIP,
+                 total_chips: int = 2, chip: ChipSpec = TPU_V5E,
                  block_size: int = 16, efficiency: float = 0.55,
                  hybrid_chunk: int = 2048, weight_bytes_per_param: float = 2.0,
                  user_mil: int = 32_768):

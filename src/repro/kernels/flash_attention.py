@@ -84,8 +84,8 @@ def _make_kernel(bq, bk, nk, window, softcap, scale, causal, kv_valid,
             # pure-padding tiles fail the causal test and never run.
             # f32 reductions: Mosaic has no integer reduce_min/max; positions
             # (< 2^24, plus the power-of-two pad value) are f32-exact.
-            pq = pq_ref[0].astype(jnp.float32)              # (bq,)
-            pk = pk_ref[0].astype(jnp.float32)              # (bk,)
+            pq = pq_ref[0, 0].astype(jnp.float32)           # (bq,)
+            pk = pk_ref[0, 0].astype(jnp.float32)           # (bk,)
             if causal:
                 run = run & (jnp.min(pk) <= jnp.max(pq))
             if window > 0:
@@ -104,8 +104,8 @@ def _make_kernel(bq, bk, nk, window, softcap, scale, causal, kv_valid,
             # token. Data-dependent, but pl.when lowers it to a branch the
             # same way as the structural causal skip. (f32 reductions: see
             # above — segment ids are small ints, exactly representable.)
-            sq = sq_ref[0].astype(jnp.float32)              # (bq,)
-            sk = sk_ref[0].astype(jnp.float32)              # (bk,)
+            sq = sq_ref[0, 0].astype(jnp.float32)           # (bq,)
+            sk = sk_ref[0, 0].astype(jnp.float32)           # (bk,)
             run = run & (jnp.min(sq) <= jnp.max(sk))
             run = run & (jnp.max(sq) >= jnp.min(sk))
             run = run & (jnp.max(sk) >= 0)
@@ -123,8 +123,8 @@ def _make_kernel(bq, bk, nk, window, softcap, scale, causal, kv_valid,
             if softcap:
                 s = softcap * jnp.tanh(s / softcap)
             if positioned:
-                qpos = jnp.broadcast_to(pq_ref[0][:, None], (bq, bk))
-                kpos = jnp.broadcast_to(pk_ref[0][None, :], (bq, bk))
+                qpos = jnp.broadcast_to(pq_ref[0, 0][:, None], (bq, bk))
+                kpos = jnp.broadcast_to(pk_ref[0, 0][None, :], (bq, bk))
             else:
                 qpos = i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
                 kpos = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
@@ -138,8 +138,8 @@ def _make_kernel(bq, bk, nk, window, softcap, scale, causal, kv_valid,
                     jnp.int32, (bq, bk), 1)
                 mask &= struct_k < kv_valid
             if segmented:
-                sq = sq_ref[0]
-                sk = sk_ref[0]
+                sq = sq_ref[0, 0]
+                sk = sk_ref[0, 0]
                 mask &= sq[:, None] == sk[None, :]
                 mask &= sk[None, :] >= 0
             s = jnp.where(mask, s, NEG_INF)
@@ -220,14 +220,21 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                      lambda b, h, i, j, g=group: (b, h // g, j, 0)),
     ]
     args = [q, k, v]
+    # per-token ids ride as (B, 1, S) in (1, 1, block) tiles: the last two
+    # block dims are then (full, multiple of 128) for any B, which Mosaic
+    # requires (a (1, block) tile of a (B, S) array is refused for B > 1)
+    q_ids = pl.BlockSpec((1, 1, bq), lambda b, h, i, j: (b, 0, i))
+    k_ids = pl.BlockSpec((1, 1, bk), lambda b, h, i, j: (b, 0, j))
+
+    def ids(x):
+        return x.astype(jnp.int32).reshape(x.shape[0], 1, x.shape[1])
+
     if segmented:
-        in_specs.append(pl.BlockSpec((1, bq), lambda b, h, i, j: (b, i)))
-        in_specs.append(pl.BlockSpec((1, bk), lambda b, h, i, j: (b, j)))
-        args += [seg_q.astype(jnp.int32), seg_k.astype(jnp.int32)]
+        in_specs += [q_ids, k_ids]
+        args += [ids(seg_q), ids(seg_k)]
     if positioned:
-        in_specs.append(pl.BlockSpec((1, bq), lambda b, h, i, j: (b, i)))
-        in_specs.append(pl.BlockSpec((1, bk), lambda b, h, i, j: (b, j)))
-        args += [pos_q.astype(jnp.int32), pos_k.astype(jnp.int32)]
+        in_specs += [q_ids, k_ids]
+        args += [ids(pos_q), ids(pos_k)]
     out_specs = pl.BlockSpec((1, 1, bq, d), lambda b, h, i, j: (b, h, i, 0))
     out_shape = jax.ShapeDtypeStruct((B, H, Sq, d), q.dtype)
     if debug_tile_map:

@@ -1,5 +1,6 @@
 """jit'd wrappers around the Pallas kernels: padding to block/MXU multiples,
-GQA layout, backend selection (interpret=True everywhere except real TPU).
+GQA layout, backend selection (compiled on TPU, interpreted on the CPU test
+platform, refused anywhere else).
 """
 from __future__ import annotations
 
@@ -15,8 +16,14 @@ from repro.kernels.fused_mlp import fused_mlp as _mlp_kernel
 from repro.kernels.rmsnorm import rmsnorm as _rmsnorm_kernel
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def _interpret() -> bool:
+    """Mosaic kernels compile on TPU; the CPU runs them in interpret mode
+    (tests). Any other backend is refused rather than silently interpreted."""
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(f"Pallas TPU kernels cannot run on backend "
+                           f"{backend!r} (tpu compiles, cpu interprets)")
+    return backend == "cpu"
 
 
 def _pad_dim(x: jax.Array, axis: int, multiple: int) -> jax.Array:
@@ -43,7 +50,7 @@ def fused_mlp(x: jax.Array, w_gate: jax.Array, w_up: jax.Array,
     wu = _pad_dim(w_up, 1, bf)
     wd = _pad_dim(w_down, 0, bf)
     out = _mlp_kernel(xp, wg, wu, wd, block_t=bt, block_f=bf,
-                      interpret=not _on_tpu())
+                      interpret=_interpret())
     return out[: xf.shape[0]].reshape(*lead, T, D)
 
 
@@ -70,7 +77,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     # (padded k rows have kpos > every real qpos), not for causal=False
     out = _flash_kernel(qt, kt, vt, causal=causal, window=window,
                         softcap=softcap, scale=d ** -0.5, kv_valid=Sk,
-                        block_q=bq, block_k=bk, interpret=not _on_tpu())
+                        block_q=bq, block_k=bk, interpret=_interpret())
     return out[:, :, :Sq].transpose(0, 2, 1, 3)
 
 
@@ -139,7 +146,7 @@ def packed_flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                         softcap=softcap, scale=d ** -0.5,
                         seg_q=seg_q, seg_k=seg_k, pos_q=pos_q, pos_k=pos_k,
                         block_q=bq, block_k=bk,
-                        interpret=not _on_tpu())
+                        interpret=_interpret())
     return out[:, :, :Sq].transpose(0, 2, 1, 3)
 
 
@@ -158,7 +165,7 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     vc = _pad_dim(v_cache, 1, bs)
     out = _decode_kernel(qh, kc, vc, kv_len.astype(jnp.int32),
                          softcap=softcap, block_s=bs,
-                         interpret=not _on_tpu())
+                         interpret=_interpret())
     return out.reshape(B, 1, H, d)
 
 
@@ -172,5 +179,5 @@ def rmsnorm(x: jax.Array, weight: jax.Array, *, eps: float = 1e-6,
     bt = min(block_t, max(8, xf.shape[0]))
     xp = _pad_dim(xf, 0, bt)
     out = _rmsnorm_kernel(xp, weight, eps=eps, block_t=bt,
-                          interpret=not _on_tpu())
+                          interpret=_interpret())
     return out[: xf.shape[0]].reshape(*lead, D)

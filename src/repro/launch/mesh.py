@@ -9,18 +9,9 @@ import jax
 
 
 def make_mesh(shape, axes):
-    """``jax.make_mesh`` across jax versions.
-
-    Newer jax exposes ``jax.sharding.AxisType`` and expects explicit
-    ``axis_types``; on older releases the attribute does not exist and
-    ``make_mesh`` defaults every axis to Auto anyway. Tests and launch code
-    build meshes through this helper so version drift stays localized here.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis Auto (sharding propagated by XLA)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -14,13 +14,25 @@ cache-affinity tie-break — exploiting the JCT predictability that is the
 paper's whole point). Admission control (MIL + deadline feasibility) and
 in-queue deadline shedding are on by default when ``--deadline`` is given.
 
-On this CPU box the instances run reduced configs with REAL forwards; on TPU
-each instance is one mesh tile (see DESIGN.md §5 instance sizing).
+Every instance runs REAL forwards. By default it serves the reduced CPU
+preset (the test configuration); ``--published-widths`` serves the
+architecture at its published widths, as on a TPU chip. Each instance owns
+one device: thread-mode replicas are placed round-robin over the local
+devices, and each process-mode worker is given its own chip. In process mode
+the frontend never initializes a JAX backend.
+
+    python -m repro.launch.serve --published-widths --max-requests 8
+    python -m repro.launch.serve --published-widths --workers 1
+
+Exits non-zero when any request ends as ``Rejected("error")`` in a run
+without chaos: a run that fails its requests never reports success.
 """
 from __future__ import annotations
 
 import argparse
+import itertools
 import json
+import sys
 import threading
 import time
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
@@ -28,14 +40,14 @@ from pathlib import Path
 from typing import Dict, Optional
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
-from repro.configs import get_config, reduce_config
+from repro.configs import get_config, serving_config
 from repro.core.engine import EngineConfig, PrefillOnlyEngine
 from repro.core.kv_policy import MemoryModel
 from repro.data.workloads import get_trace
 from repro.models.model import build
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.runtime.fault_tolerance import (InstancePool,
                                            JCTDeadlineWatchdog,
                                            PreemptionHandler)
@@ -47,7 +59,8 @@ from repro.serving import (AdmissionController, AsyncServer,
                            wrap_pool_processes)
 
 
-def make_pool(arch: str, n_instances: int = 2, *, reduced: bool = True,
+def make_pool(arch: str, n_instances: int = 2, *,
+              published_widths: bool = False,
               policy: str = "srjf_calibrated", lam: float = 0.05,
               cache_tokens: int = 4096, seed: int = 0,
               profile: bool = False, offload: bool = False,
@@ -61,17 +74,24 @@ def make_pool(arch: str, n_instances: int = 2, *, reduced: bool = True,
     prepacking budget from the fitted curve. ``offload=True`` gives every
     instance the DRAM KV tier (``host_cache_mb`` per instance): evicted
     prefix blocks demote to host memory and restore instead of recomputing.
+
+    Instances are placed round-robin over ``jax.local_devices()``: on a
+    four-chip host, four instances are four one-chip replicas, each holding
+    its own parameters and KV.
     """
-    cfg = get_config(arch)
-    if reduced:
-        cfg = reduce_config(cfg, hybrid_chunk=0)
+    cfg = serving_config(arch, published_widths)
     api = build(cfg)
-    params = materialize(jax.random.PRNGKey(seed), api.defs(), jnp.float32)
+    # the compute dtype directly (the values the engine's cast would give):
+    # the pool's closure keeps this copy alive, so no f32 copy on the device
+    params = materialize(jax.random.PRNGKey(seed), api.defs(), cfg.dtype)
+    devices = jax.local_devices()
+    placed = itertools.count()
 
     def make_engine(name: str) -> PrefillOnlyEngine:
         eng = PrefillOnlyEngine(cfg, params, EngineConfig(
             policy=policy, lam=lam, cache_capacity_tokens=cache_tokens,
-            offload=offload, host_cache_bytes=host_cache_mb << 20))
+            offload=offload, host_cache_bytes=host_cache_mb << 20),
+            device=devices[next(placed) % len(devices)])
         if profile:
             eng.profile(profile_lengths)
         return eng
@@ -81,7 +101,8 @@ def make_pool(arch: str, n_instances: int = 2, *, reduced: bool = True,
     return pool
 
 
-def make_worker_pool(arch: str, n_workers: int, *, reduced: bool = True,
+def make_worker_pool(arch: str, n_workers: int, *,
+                     published_widths: bool = False,
                      policy: str = "srjf_calibrated", lam: float = 0.05,
                      cache_tokens: int = 4096, seed: int = 0,
                      profile: bool = False, offload: bool = False,
@@ -98,7 +119,8 @@ def make_worker_pool(arch: str, n_workers: int, *, reduced: bool = True,
     back so the frontend only spends prefetch RPCs on tiered workers."""
     ecfg = ({"offload": True, "host_cache_bytes": host_cache_mb << 20}
             if offload else {})
-    specs = {f"inst{i}": {"kind": "engine", "arch": arch, "reduced": reduced,
+    specs = {f"inst{i}": {"kind": "engine", "arch": arch,
+                          "published_widths": published_widths,
                           "policy": policy, "lam": lam,
                           "cache_tokens": cache_tokens, "seed": seed,
                           "profile": profile, "ecfg": ecfg}
@@ -190,7 +212,8 @@ def serve_trace(arch: str = "qwen1.5-0.5b",
                 trace_capacity: int = 4096,
                 offload: bool = False,
                 host_cache_mb: int = 256,
-                cache_tokens: int = 4096) -> Dict:
+                cache_tokens: int = 4096,
+                published_widths: bool = False) -> Dict:
     """Replay a paper workload through the AsyncServer. Returns latency
     stats over SERVED requests plus rejection counts and a telemetry dump.
 
@@ -213,18 +236,24 @@ def serve_trace(arch: str = "qwen1.5-0.5b",
     in process mode injects the process/RPC fault kinds (``kill``,
     ``freeze``, ``rpc_drop``, ``rpc_delay``); the in-process step/submit
     kinds only apply in thread mode.
+
+    ``published_widths`` serves ``arch`` at its published widths instead of
+    the reduced CPU preset (``configs.serving_config``).
     """
     plan = FaultPlan(chaos) if chaos is not None else None
     sup = None
     if workers and pool is None:
         pool, sup = make_worker_pool(
-            arch, workers, policy=policy, lam=lam, seed=seed,
+            arch, workers, published_widths=published_widths,
+            policy=policy, lam=lam, seed=seed,
             profile=profile, offload=offload, host_cache_mb=host_cache_mb,
             cache_tokens=cache_tokens,
             rpc_fault_hook=plan.rpc_fault if plan is not None else None,
             drain_grace=min(drain_timeout or 30.0, 30.0))
     elif pool is None:
-        pool = make_pool(arch, n_instances, policy=policy, lam=lam,
+        pool = make_pool(arch, n_instances,
+                         published_widths=published_widths,
+                         policy=policy, lam=lam,
                          seed=seed, profile=profile, offload=offload,
                          host_cache_mb=host_cache_mb,
                          cache_tokens=cache_tokens)
@@ -235,19 +264,21 @@ def serve_trace(arch: str = "qwen1.5-0.5b",
         # MIL from the engines' own model config unless given explicitly —
         # the same closed form the profile run sizes the KV budget with.
         # Remote engines hold no model config frontend-side; rebuild the
-        # (weights-free) config the workers were spawned with.
-        eng_cfg = getattr(next(iter(pool.engines.values())), "cfg", None)
+        # (weights-free) config the workers were spawned with. The chip is
+        # the engine's own (a worker reports its device in its hello).
+        any_eng = next(iter(pool.engines.values()))
+        eng_cfg = getattr(any_eng, "cfg", None)
         if eng_cfg is None:
-            eng_cfg = reduce_config(get_config(arch), hybrid_chunk=0)
+            eng_cfg = serving_config(arch, published_widths)
         # price the engines' actual KV lifecycle into the MIL gate: finite
         # kv_keep means peak-layer suffix footprint, not all-layers
-        any_eng = next(iter(pool.engines.values()))
         kv_keep = getattr(getattr(any_eng, "ecfg", None),
                           "kv_keep_tokens", None)
         if kv_keep is not None and kv_keep >= 10**9:
             kv_keep = None
         ctrl = AdmissionController(max_input_tokens=max_input_tokens,
-                                   memory_model=MemoryModel(eng_cfg),
+                                   memory_model=MemoryModel(
+                                       eng_cfg, any_eng.chip),
                                    kv_keep=kv_keep)
     # always-on request-lifecycle tracing: the ring bounds memory and the
     # per-event cost is one lock + list append (<3% on the packing
@@ -381,6 +412,9 @@ def _replay(server, arch, trace_name, qps, scale_tokens, seed, max_requests,
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen1.5-0.5b")
+    ap.add_argument("--published-widths", action="store_true",
+                    help="serve --arch at its published widths (default: "
+                         "the reduced CPU test preset)")
     ap.add_argument("--trace", default="post_recommendation")
     ap.add_argument("--qps", type=float, default=5.0)
     ap.add_argument("--instances", type=int, default=2)
@@ -460,6 +494,7 @@ def main():
     chaos.add_argument("--chaos-rpc-delay-seconds", type=float,
                        default=0.05)
     args = ap.parse_args()
+    enable_compile_cache()
     chaos_cfg = None
     if any(r > 0 for r in (args.chaos_step_error, args.chaos_hang,
                            args.chaos_straggler, args.chaos_nan,
@@ -495,7 +530,8 @@ def main():
                       trace_dump=args.trace_dump,
                       offload=args.offload,
                       host_cache_mb=args.host_cache_mb,
-                      cache_tokens=args.cache_tokens)
+                      cache_tokens=args.cache_tokens,
+                      published_widths=args.published_widths)
     for k, v in out.items():
         if k == "metrics":
             if args.dump_metrics:
@@ -510,6 +546,12 @@ def main():
                   f"resid_p50={fit['residual_p50']:.4f} "
                   f"resid_p95={fit['residual_p95']:.4f} "
                   f"refits={fit['refits']}+{fit['drift_refits']}")
+    errors = out["reject_reasons"].get("error", 0)
+    if errors and chaos_cfg is None:
+        # without injected faults, an errored request is a broken engine
+        print(f"FAILED: {errors} request(s) ended Rejected('error')",
+              file=sys.stderr)
+        sys.exit(1)
 
 
 if __name__ == "__main__":

@@ -194,11 +194,11 @@ def run_live_smoke(n_requests: int = 12, arch: str = "qwen1.5-0.5b",
     """
     import numpy as np
 
-    from repro.configs import get_config, reduce_config
+    from repro.configs import serving_config
     from repro.launch.serve import start_metrics_server
     from repro.serving import AsyncServer, SpanTracer
 
-    cfg = reduce_config(get_config(arch), hybrid_chunk=0)
+    cfg = serving_config(arch)
     sup = None
     # tier-exercising engine shape: the device cache holds only 4 blocks
     # (64 tokens — two 40-token requests' kept KV), so the first submission
@@ -213,8 +213,7 @@ def run_live_smoke(n_requests: int = 12, arch: str = "qwen1.5-0.5b",
         from repro.serving import make_process_pool, wire_supervisor
         # solo packing + same-length requests below: after the first
         # (compile) step every step is warm -> JCT monitor has samples
-        specs = {f"inst{i}": {"kind": "engine", "arch": arch,
-                              "reduced": True, "seed": 0,
+        specs = {f"inst{i}": {"kind": "engine", "arch": arch, "seed": 0,
                               "ecfg": dict(tier_ecfg)}
                  for i in range(workers)}
         pool, sup = make_process_pool(
@@ -346,6 +345,8 @@ def main() -> None:
         if args.jsonl:
             validate_dump_files(args.jsonl)
         else:
+            from repro.runtime.compile_cache import enable_compile_cache
+            enable_compile_cache()
             run_live_smoke(args.requests, args.arch, workers=args.workers)
     except (AssertionError, ValueError, KeyError) as e:
         print(f"SMOKE FAILED: {e}", file=sys.stderr)

@@ -1,11 +1,15 @@
-"""Hardware constants for the TARGET platform (TPU v5e) + roofline helpers.
+"""Peak rates of the chips the engine prices against, keyed by device kind.
 
-This container is CPU-only; these constants drive the analytic roofline
-terms, the MIL memory model, and the simulator's JCT cost model.
+``MemoryModel`` admission and the KV tier's ``OffloadPolicy`` price against
+the chip an engine actually runs on: ``chip_for`` looks its peaks up from the
+device's ``device_kind`` and refuses a kind it has no table entry for. The
+simulator and the analytic roofline model the v5e target and name
+``TPU_V5E`` explicitly.
 """
 from __future__ import annotations
 
 import dataclasses
+from typing import Dict
 
 
 @dataclasses.dataclass(frozen=True)
@@ -19,6 +23,8 @@ class ChipSpec:
     host_bw: float = 25e9       # bytes/s host<->device (PCIe/DMA)
 
 
+# Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB HBM at
+# 819 GB/s, 1,600 Gbit/s chip-to-chip interconnect (4 links).
 TPU_V5E = ChipSpec(
     name="tpu-v5e",
     peak_flops_bf16=197e12,
@@ -32,25 +38,25 @@ TPU_V5E = ChipSpec(
 TPU_V5E_SLOW_LINKS = dataclasses.replace(TPU_V5E, name="tpu-v5e-dcn",
                                          ici_bw=6.25e9)
 
-DEFAULT_CHIP = TPU_V5E
+# jax.Device.device_kind -> peaks
+CHIPS: Dict[str, ChipSpec] = {
+    "TPU v5 lite": TPU_V5E,
+}
 
 
-def compute_seconds(flops: float, chips: int = 1,
-                    chip: ChipSpec = DEFAULT_CHIP, efficiency: float = 1.0) -> float:
-    return flops / (chips * chip.peak_flops_bf16 * efficiency)
+def chip_for(platform: str, device_kind: str) -> ChipSpec:
+    """Peaks of a device given its ``platform`` and ``device_kind``.
 
-
-def memory_seconds(bytes_moved: float, chips: int = 1,
-                   chip: ChipSpec = DEFAULT_CHIP) -> float:
-    return bytes_moved / (chips * chip.hbm_bw)
-
-
-def collective_seconds(bytes_moved: float, chips: int = 1,
-                       chip: ChipSpec = DEFAULT_CHIP) -> float:
-    return bytes_moved / (chips * chip.ici_bw)
-
-
-def host_transfer_seconds(bytes_moved: float,
-                          chip: ChipSpec = DEFAULT_CHIP) -> float:
-    """Host<->device copy time over the PCIe/DMA link (offload tier)."""
-    return bytes_moved / chip.host_bw
+    A CPU device runs the test rehearsal of the v5e path (reduced preset,
+    interpret-mode kernels) and prices as ``TPU_V5E``. Any other device must
+    be in ``CHIPS``: no peak rate is assumed for a kind this table lacks.
+    """
+    if platform == "cpu":
+        return TPU_V5E
+    try:
+        return CHIPS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no peak rates for device kind {device_kind!r} "
+            f"(platform {platform!r}); add it to repro.runtime.hw.CHIPS "
+            f"(known: {sorted(CHIPS)})") from None

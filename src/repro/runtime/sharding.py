@@ -133,20 +133,11 @@ def constrain(x: jax.Array, axes: Axes) -> jax.Array:
 
 
 def shard_map(fn, *, mesh, in_specs, out_specs):
-    """``jax.shard_map`` across jax versions.
-
-    Newer jax promotes shard_map to the top level and renames the replication
-    check to ``check_vma``; older releases have it under ``jax.experimental``
-    as ``check_rep``. The check is disabled either way: our collective
+    """``jax.shard_map`` with the replication check off: our collective
     schedules (psum of combined partials, all-gathered K/V) are hand-pinned
-    and the checker rejects them.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=False)
+    and the checker rejects them."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 # --------------------------------------------------------------------------

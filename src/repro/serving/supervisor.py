@@ -57,6 +57,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 from repro.core.scheduler import Request, _req_counter
 from repro.runtime.fault_tolerance import InstancePool
+from repro.runtime.hw import chip_for
 from repro.serving.rpc import (RpcClient, RpcDropped, RpcError,
                                RpcRemoteError)
 from repro.serving.tracing import BatchRecord
@@ -108,6 +109,7 @@ class RemoteEngine:
         self._metrics = None
         self._tracer = None
         self.offload = False          # set from the worker's hello
+        self.chip = None              # the worker's ChipSpec, from hello
         self._host_kv: Dict = {}      # tier occupancy from the heartbeat
 
     # ---- engine protocol: submission ------------------------------------
@@ -479,12 +481,39 @@ class RemoteEngine:
             return out
 
 
+def _chip_env(slot: int) -> Dict[str, str]:
+    """libtpu visibility for the worker in ``slot``: one chip per process.
+
+    A TPU chip belongs to one process; workers spawned from one environment
+    would otherwise all reach for every local chip. Worker ``slot`` sees
+    only the ``slot``-th chip the frontend may use (``TPU_VISIBLE_CHIPS``
+    when the caller restricted it, else every chip of the host), as a
+    one-chip slice of its own. Off TPU, libtpu is not loaded and these
+    variables are inert.
+    """
+    allowed = os.environ.get("TPU_VISIBLE_CHIPS")
+    if allowed:
+        chips = [c.strip() for c in allowed.split(",") if c.strip()]
+        if slot >= len(chips):
+            raise RuntimeError(
+                f"worker slot {slot} has no chip: TPU_VISIBLE_CHIPS="
+                f"{allowed!r} allows {len(chips)} worker(s), one per chip")
+        chip = chips[slot]
+    else:
+        chip = str(slot)
+    return {"TPU_VISIBLE_CHIPS": chip,
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_PORT": str(8476 + slot)}
+
+
 class WorkerHandle:
     """One supervised worker process and its client-side plumbing."""
 
-    def __init__(self, name: str, spec: Dict):
+    def __init__(self, name: str, spec: Dict, slot: int = 0):
         self.name = name
         self.spec = spec
+        self.slot = slot              # the chip this worker owns on a TPU
         self.proc: Optional[subprocess.Popen] = None
         self.pid: Optional[int] = None
         self.port: Optional[int] = None
@@ -571,6 +600,7 @@ class WorkerSupervisor:
         src = os.path.dirname(os.path.dirname(os.path.dirname(
             os.path.abspath(__file__))))
         env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
+        env.update(_chip_env(h.slot))
         # append mode: a restarted worker's logs continue the same files —
         # the CI chaos soak uploads these on failure
         with open(os.path.join(self.log_dir, f"{h.name}.out.log"),
@@ -601,7 +631,9 @@ class WorkerSupervisor:
         h.misses = 0
 
     def spawn(self, name: str, spec: Dict) -> WorkerHandle:
-        h = WorkerHandle(name, spec)
+        taken = {o.slot for o in self.handles.values()}
+        h = WorkerHandle(name, spec,
+                         slot=min(set(range(len(taken) + 1)) - taken))
         self.handles[name] = h
         self._launch(h)
         hook = None
@@ -613,6 +645,8 @@ class WorkerSupervisor:
         hello = h.client.call("hello", timeout=15.0)
         h.remote.ecfg.block_size = int(hello["block_size"])
         h.remote.offload = bool(hello.get("offload"))
+        if hello.get("device"):
+            h.remote.chip = chip_for(*hello["device"])
         self._log(f"worker {name}: pid={h.pid} port={h.port} "
                   f"block_size={h.remote.ecfg.block_size} "
                   f"offload={h.remote.offload}")

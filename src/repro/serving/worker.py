@@ -168,14 +168,13 @@ def build_engine(name: str, spec: Dict):
     import jax
     import jax.numpy as jnp
 
-    from repro.configs import get_config, reduce_config
+    from repro.configs import serving_config
     from repro.core.engine import EngineConfig, PrefillOnlyEngine
     from repro.models.model import build
     from repro.runtime.sharding import materialize
 
-    cfg = get_config(spec.get("arch", "qwen1.5-0.5b"))
-    if spec.get("reduced", True):
-        cfg = reduce_config(cfg, hybrid_chunk=0)
+    cfg = serving_config(spec.get("arch", "qwen1.5-0.5b"),
+                         bool(spec.get("published_widths", False)))
     api = build(cfg)
     params = materialize(jax.random.PRNGKey(int(spec.get("seed", 0))),
                          api.defs(), jnp.float32)
@@ -412,12 +411,17 @@ class EngineWorker:
     def _op_hello(self, p: Dict) -> Dict:
         # offload: duck-typed (a tiered cache carries a host store) so the
         # fake engine stays import-light; the frontend uses the flag to
-        # skip prefetch/estimate RPCs entirely on un-tiered workers
+        # skip prefetch/estimate RPCs entirely on un-tiered workers. The
+        # device rides along so the frontend can price admission against
+        # the worker's chip without touching a JAX backend itself.
+        dev = getattr(self.engine, "device", None)
         return {"pid": os.getpid(), "name": self.name,
                 "block_size": self.engine.ecfg.block_size,
                 "offload": getattr(
                     getattr(self.engine, "cache", None), "host", None)
                 is not None,
+                "device": (None if dev is None
+                           else [dev.platform, dev.device_kind]),
                 "now": time.perf_counter()}
 
     def _op_shutdown(self, p: Dict) -> Dict:
@@ -517,7 +521,11 @@ def main() -> int:
     ap.add_argument("--drain-grace", type=float, default=5.0,
                     help="max seconds to wait out the queue after SIGTERM")
     args = ap.parse_args()
-    engine = build_engine(args.name, json.loads(args.spec))
+    spec = json.loads(args.spec)
+    if spec.get("kind", "fake") == "engine":     # fakes never import jax
+        from repro.runtime.compile_cache import enable_compile_cache
+        enable_compile_cache()
+    engine = build_engine(args.name, spec)
     worker = EngineWorker(args.name, engine, lease=args.lease,
                           drain_grace=args.drain_grace)
     return worker.run(args.port_file)

@@ -6,6 +6,7 @@ from repro.configs import get_config
 from repro.core.jct import (GridJCT, LinearProxyJCT, RooflineJCT, pearson,
                             tp_comm_bytes_per_token)
 from repro.core.kv_policy import MemoryModel
+from repro.runtime.hw import TPU_V5E
 
 
 def test_linear_proxy_fit_recovers_slope():
@@ -63,7 +64,7 @@ def test_mil_ordering_matches_paper():
     """Table 2's qualitative ordering on a single accelerator:
     paged < discard-only < chunked < hybrid; TP-2 > paged."""
     cfg = get_config("llama3.1-8b")
-    mm = MemoryModel(cfg, weight_bytes_per_param=1.0)
+    mm = MemoryModel(cfg, TPU_V5E, weight_bytes_per_param=1.0)
     mil = mm.mil_table()
     assert mil["paged"] < mil["discard"]
     assert mil["paged"] < mil["chunked"]
@@ -75,7 +76,7 @@ def test_mil_ordering_matches_paper():
 def test_discard_alone_is_marginal():
     """Paper §2.6: naive KV discard gives only ~1.6x (intermediates bound)."""
     cfg = get_config("llama3.1-8b")
-    mm = MemoryModel(cfg, weight_bytes_per_param=1.0)
+    mm = MemoryModel(cfg, TPU_V5E, weight_bytes_per_param=1.0)
     mil = mm.mil_table()
     assert mil["discard"] / mil["paged"] < 2.5
 
@@ -83,23 +84,23 @@ def test_discard_alone_is_marginal():
 def test_mlp_intermediates_dominate_one_layer_kv():
     """Fig 4: intermediate tensors ~14x one-layer KV on Llama-3.1-8B."""
     cfg = get_config("llama3.1-8b")
-    mm = MemoryModel(cfg)
+    mm = MemoryModel(cfg, TPU_V5E)
     ratio = mm.mlp_int_per_token / mm.kv_one_layer_per_token
     assert 10 < ratio < 20
 
 
 def test_prefix_budget_positive_at_workload_mil():
     cfg = get_config("llama3.1-8b")
-    mm = MemoryModel(cfg, weight_bytes_per_param=1.0)
+    mm = MemoryModel(cfg, TPU_V5E, weight_bytes_per_param=1.0)
     assert mm.prefix_budget_tokens(20_000) > 10_000
 
 
 def test_hybrid_micro_optimizations_increase_mil():
     """§4.3 output-preallocation / in-place ablation (Fig 10 steps)."""
     cfg = get_config("llama3.1-8b")
-    base = MemoryModel(cfg, weight_bytes_per_param=1.0,
+    base = MemoryModel(cfg, TPU_V5E, weight_bytes_per_param=1.0,
                        output_prealloc=False, inplace=False)
-    opt = MemoryModel(cfg, weight_bytes_per_param=1.0)
+    opt = MemoryModel(cfg, TPU_V5E, weight_bytes_per_param=1.0)
     assert opt.max_input_length("hybrid") >= base.max_input_length("hybrid")
     # chunked technique depends on the act coefficient too
     assert opt.peak_bytes(32_768, "paged") < base.peak_bytes(32_768, "paged")

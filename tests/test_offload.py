@@ -4,6 +4,7 @@ import numpy as np
 from repro.configs import get_config
 from repro.core.offload import HostKVStore, OffloadPolicy, TieredPrefixCache
 from repro.core.prefix_cache import token_chain
+from repro.runtime.hw import TPU_V5E
 
 BLOCK = 4
 CFG = get_config("llama3.1-8b")
@@ -19,7 +20,8 @@ def _payloads(chain):
 
 
 def test_evicted_blocks_land_in_host_store():
-    c = TieredPrefixCache(2, BLOCK, cfg=CFG)
+    c = TieredPrefixCache(2, BLOCK, cfg=CFG,
+                          policy=OffloadPolicy(TPU_V5E))
     a = _chain(8, seed=1)
     c.insert(a, 8, payloads=_payloads(a))
     b = _chain(8, seed=2)
@@ -29,7 +31,8 @@ def test_evicted_blocks_land_in_host_store():
 
 
 def test_match_restores_from_host():
-    c = TieredPrefixCache(2, BLOCK, cfg=CFG)
+    c = TieredPrefixCache(2, BLOCK, cfg=CFG,
+                          policy=OffloadPolicy(TPU_V5E))
     a = _chain(8, seed=1)
     c.insert(a, 8, payloads=_payloads(a))
     b = _chain(8, seed=2)
@@ -54,9 +57,9 @@ def test_host_store_capacity_lru():
 
 
 def test_policy_breakeven():
-    pol = OffloadPolicy()
+    pol = OffloadPolicy(TPU_V5E)
     # an 8B model: restoring a 16-token block (~2 MB) beats recomputing
     assert pol.worth_restoring(CFG, 16, 2 * 2**20)
     # absurdly slow link -> recompute wins
-    slow = OffloadPolicy(host_bw=1e3)
+    slow = OffloadPolicy(TPU_V5E, host_bw=1e3)
     assert not slow.worth_restoring(CFG, 16, 2 * 2**20)

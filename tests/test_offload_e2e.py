@@ -11,8 +11,9 @@ import jax.numpy as jnp
 from repro.configs import get_config, reduce_config
 from repro.core.engine import EngineConfig, PrefillOnlyEngine
 from repro.core.kv_policy import KVLifecycle, MemoryModel
-from repro.core.offload import TieredPrefixCache
+from repro.core.offload import OffloadPolicy, TieredPrefixCache
 from repro.models.model import build
+from repro.runtime.hw import TPU_V5E
 from repro.runtime.sharding import materialize
 
 # 4-block device cache + solo packing + fine reuse granularity: two
@@ -154,7 +155,7 @@ def test_prefetch_upgrades_host_blocks_to_device(setup):
 
 def test_pinned_blocks_survive_tiered_eviction():
     from repro.core.prefix_cache import token_chain
-    c = TieredPrefixCache(2, 4)
+    c = TieredPrefixCache(2, 4, policy=OffloadPolicy(TPU_V5E))
     a = token_chain([1, 2, 3, 4, 5, 6, 7, 8], 4)
     c.insert(a, 8, payloads=[(np.ones((2, 4), np.float32),)] * 2)
     c.pin(a, 2)                              # running request holds it
@@ -183,7 +184,7 @@ def test_kv_lifecycle_keep_arithmetic():
 
 def test_memory_model_kv_keep_prices_peak_layer():
     cfg = get_config("llama3.1-8b")
-    mm = MemoryModel(cfg)
+    mm = MemoryModel(cfg, TPU_V5E)
     S = 1 << 16
     unpriced = mm.peak_bytes(S, "hybrid")
     capped = mm.peak_bytes(S, "hybrid", kv_keep=1024)
@@ -198,7 +199,7 @@ def test_memory_model_mil_knee_and_prefix_budget():
     cfg = get_config("llama3.1-8b")
     # fp8 weights — the paper's quantized serving setup; fp16 weights alone
     # would exceed the default chip's HBM and zero out every MIL
-    mm = MemoryModel(cfg, weight_bytes_per_param=1)
+    mm = MemoryModel(cfg, TPU_V5E, weight_bytes_per_param=1)
     mil_all = mm.max_input_length("hybrid", kv_keep=1 << 30)  # keep all
     mil_cap = mm.max_input_length("hybrid", kv_keep=1024)
     mil_un = mm.max_input_length("hybrid")

@@ -1,7 +1,7 @@
 """Prefix-aware packed prefill (the packed cache-HIT path): kernel ->
 oracle -> transformer -> engine equivalence against the solo suffix path,
-the prefix-tile-skip guarantee, TPU lowering of the positioned kernel, and
-the engine's {solo suffix, packed miss, packed hit} cost model."""
+the prefix-tile-skip guarantee, and the engine's {solo suffix, packed miss,
+packed hit} cost model."""
 import dataclasses
 
 import jax
@@ -148,31 +148,6 @@ def test_prefix_tiles_of_other_segments_are_skipped():
     # ...while each hits its OWN prefix tiles
     assert tmap[0, 0] == 1 and tmap[0, 1] == 1
     assert tmap[1, 2] == 1 and tmap[1, 3] == 1
-
-
-def test_positioned_kernel_lowers_for_tpu():
-    """The positioned (prefix-aware) and segmented kernels both lower to a
-    Mosaic TPU custom call — the f32 tile-skip reductions keep Mosaic's
-    no-integer-reductions constraint satisfied. (Execution on real TPU
-    remains a ROADMAP item; lowering structure is validated here.)"""
-    q = jnp.zeros((1, 2, 256, 128), jnp.float32)
-    k = v = jnp.zeros((1, 1, 256, 128), jnp.float32)
-    seg = jnp.zeros((1, 256), jnp.int32)
-    pos = jnp.zeros((1, 256), jnp.int32)
-
-    def positioned(q, k, v):
-        return raw_flash(q, k, v, seg_q=seg, seg_k=seg, pos_q=pos,
-                         pos_k=pos, block_q=128, block_k=128,
-                         interpret=False)
-
-    def segmented(q, k, v):
-        return raw_flash(q, k, v, seg_q=seg, seg_k=seg, block_q=128,
-                         block_k=128, interpret=False)
-
-    for fn in (positioned, segmented):
-        txt = jax.jit(fn).trace(q, k, v).lower(
-            lowering_platforms=("tpu",)).as_text()
-        assert "tpu_custom_call" in txt
 
 
 # --------------------------------------------------------------------------

@@ -220,7 +220,8 @@ def test_admission_mil_reject():
 def test_admission_mil_from_memory_model():
     from repro.configs import get_config
     from repro.core.kv_policy import MemoryModel
-    mm = MemoryModel(get_config("llama3.1-8b"))
+    from repro.runtime.hw import TPU_V5E
+    mm = MemoryModel(get_config("llama3.1-8b"), TPU_V5E)
     ctrl = AdmissionController(memory_model=mm)
     assert ctrl.max_input_tokens == mm.max_input_length("hybrid", 2048)
     assert ctrl.check(ctrl.max_input_tokens + 1, None, 0, 0, 0).reason \
