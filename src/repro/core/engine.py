@@ -74,7 +74,7 @@ from repro.models.layers import PAD_POS
 from repro.models.model import cast_params
 from repro.runtime.fault_tolerance import NaNGuard
 from repro.runtime.hw import chip_for
-from repro.serving.tracing import BatchRecord, JCTCalibrationMonitor
+from repro.serving.tracing import BatchRecord, JCTCalibrationMonitor, Phase
 
 
 @dataclasses.dataclass
@@ -230,6 +230,8 @@ class PrefillOnlyEngine:
         self.tracer = None
         self._last_jit: Tuple[str, Tuple, bool] = ("", (), False)
         self._last_shape: Dict[str, int] = {}
+        self._phases: Dict[str, float] = {}    # host seconds per phase of
+                                               # the current step (Phase)
 
     # ---- profile run (paper §3.1) ------------------------------------------
     def profile(self, lengths: Sequence[int] = (64, 128, 256, 512)) -> float:
@@ -550,9 +552,27 @@ class PrefillOnlyEngine:
 
     def step(self) -> Optional[int]:
         """One scheduling step: pick (Algorithm 1), form a packed batch,
-        prefill, cache, score. Returns the anchor request's id."""
+        prefill, cache, score. Returns the anchor request's id.
+
+        A step with queued work runs inside the profiler span
+        ``engine.step``, and each of its host phases (form_batch,
+        cache_match, kv_gather, dispatch, device_wait, kv_insert, score,
+        record) is a ``Phase``: a leaf span of its own and its seconds in
+        the step's ``BatchRecord.phases``. The worker polls ``step()``, so
+        an empty queue returns before any span opens."""
+        if not self.queue:
+            return None
+        self._phases = {}
+        with jax.profiler.TraceAnnotation("engine.step"):
+            return self._step()
+
+    def _phase(self, name: str) -> Phase:
+        return Phase(self._phases, name)
+
+    def _step(self) -> Optional[int]:
         now = time.perf_counter()
-        batch = self._form_batch(now)
+        with self._phase("form_batch"):
+            batch = self._form_batch(now)
         if batch is None:
             return None
         for r in batch:
@@ -571,9 +591,10 @@ class PrefillOnlyEngine:
             logits = self._execute(r)
             # async dispatch: sync before timestamping, or the JCT model
             # observes launch latency instead of compute time
-            jax.block_until_ready(logits)
+            with self._phase("device_wait"):
+                jax.block_until_ready(logits)
             done = r.finish_time = time.perf_counter()
-            with self.lock:
+            with self._phase("score"), self.lock:
                 self.results[r.req_id] = self._score(logits, r)
                 # steps that compiled a fresh shape are NOT JCT samples — a
                 # multi-second jit compile recorded as serving cost wrecks the
@@ -583,9 +604,10 @@ class PrefillOnlyEngine:
                                            r.finish_time - now)
         else:
             logits = self._execute_packed(batch)
-            jax.block_until_ready(logits)
+            with self._phase("device_wait"):
+                jax.block_until_ready(logits)
             done = time.perf_counter()
-            with self.lock:
+            with self._phase("score"), self.lock:
                 for n, r in enumerate(batch):
                     r.finish_time = done
                     self.results[r.req_id] = self._score(logits[n:n + 1], r)
@@ -604,7 +626,8 @@ class PrefillOnlyEngine:
                 1 for r in batch if r.n_cached_at_start > 0)
         self.steps += 1
         self._last_step_ids = [r.req_id for r in batch]
-        self._record_step(batch, now, done, time.perf_counter(), padded0)
+        with self._phase("record"):
+            self._record_step(batch, now, done, time.perf_counter(), padded0)
         with self.lock:
             self._inflight = []
             self._inflight_pred = 0.0
@@ -613,7 +636,9 @@ class PrefillOnlyEngine:
     def _record_step(self, batch: List[Request], t0: float, t_done: float,
                      t_scored: float, padded0: int) -> None:
         """Observability epilogue of step(): BatchRecord into the ring, JCT
-        calibration sample (warm steps only), per-request trace spans."""
+        calibration sample (warm steps only), per-request trace spans. The
+        record shares the step's phase dict, so its ``record`` entry (this
+        epilogue's own cost) lands when the phase closes."""
         pred = self._inflight_pred
         computed = sum(r.n_input - r.n_cached_at_start for r in batch)
         kind = ("solo" if len(batch) == 1
@@ -631,7 +656,7 @@ class PrefillOnlyEngine:
             smax=shape.get("smax", 0), pmax=shape.get("pmax", 0),
             K=shape.get("K", 0), jit_path=path, jit_key=key,
             compiled=self._step_compiled, predicted_jct=pred,
-            wall=t_done - t0)
+            wall=t_done - t0, phases=self._phases)
         self.batch_records.append(rec)
         # compile steps are excluded from calibration for the same reason
         # they are excluded from the JCT fit: compile time is unbounded and
@@ -644,8 +669,6 @@ class PrefillOnlyEngine:
                                    rec.pmax, rec.wall)
         m = self.metrics
         if m is not None:
-            m.gauge("step_padding_waste", self.instance_name).set(
-                rec.padding_waste)
             m.histogram("padding_waste", self.instance_name).observe(
                 rec.padding_waste)
             m.counter("padded_slots", self.instance_name).inc(
@@ -658,13 +681,9 @@ class PrefillOnlyEngine:
                 m.gauge("host_kv_used_bytes", self.instance_name,
                         help="DRAM offload tier occupancy").set(
                     hs["used_bytes"])
-                m.gauge("host_kv_blocks", self.instance_name).set(
-                    hs["blocks"])
                 m.gauge("kv_offload_blocks", self.instance_name,
                         help="KV blocks demoted device->host (cumulative)"
                         ).set(hs["offloads"])
-                m.gauge("kv_offload_bytes", self.instance_name).set(
-                    hs["offload_bytes"])
         tr = self.tracer
         if tr is None:
             return
@@ -878,34 +897,39 @@ class PrefillOnlyEngine:
         # cache probe + pin under the lock; the forward itself runs outside
         # it so router/admission probes never block on compute
         with self.lock:
-            matched = self._match_restoring(r.chain, rid=r.req_id)
-            prefix_len = self._usable_prefix_len(r.n_input, matched)
-            use_blocks = prefix_len // bs
-            r.n_cached_at_start = prefix_len
-            self.hit_tokens += prefix_len
-            self.total_tokens += r.n_input
-            self.padded_slots += prefix_len + _bucket(
-                r.n_input - prefix_len, self.ecfg.suffix_buckets)
-            keep = self.kv.keep(r.n_input)
-            # chain already resident past the keep bound: the insert below
-            # would only re-slice and re-touch existing blocks — skip it
-            # (the match walk above refreshed their LRU standing)
-            resident = self.kv.resident(matched, r.n_input)
+            with self._phase("cache_match"):
+                matched = self._match_restoring(r.chain, rid=r.req_id)
+                prefix_len = self._usable_prefix_len(r.n_input, matched)
+                use_blocks = prefix_len // bs
+                r.n_cached_at_start = prefix_len
+                self.hit_tokens += prefix_len
+                self.total_tokens += r.n_input
+                self.padded_slots += prefix_len + _bucket(
+                    r.n_input - prefix_len, self.ecfg.suffix_buckets)
+                keep = self.kv.keep(r.n_input)
+                # chain already resident past the keep bound: the insert
+                # below would only re-slice and re-touch existing blocks —
+                # skip it (the match walk above refreshed their LRU standing)
+                resident = self.kv.resident(matched, r.n_input)
+                if prefix_len:
+                    self.cache.pin(r.chain, use_blocks)
+                    payloads = self.cache.match_payloads(
+                        r.chain)[:use_blocks]
             if prefix_len:
-                self.cache.pin(r.chain, use_blocks)
-                payloads = self.cache.match_payloads(r.chain)[:use_blocks]
-                pk = jnp.concatenate([p[0] for p in payloads], axis=2)
-                pv = jnp.concatenate([p[1] for p in payloads], axis=2)
-        if prefix_len == 0:
-            logits, new_kv, n_new = self._run_fresh(r.tokens, keep)
-            kv_from = 0
-        else:
-            logits, new_kv, n_new = self._run_suffix(
-                r.tokens[prefix_len:], pk, pv, prefix_len, keep)
-            kv_from = prefix_len
+                with self._phase("kv_gather"):
+                    pk = jnp.concatenate([p[0] for p in payloads], axis=2)
+                    pv = jnp.concatenate([p[1] for p in payloads], axis=2)
+        with self._phase("dispatch"):
+            if prefix_len == 0:
+                logits, new_kv, n_new = self._run_fresh(r.tokens, keep)
+                kv_from = 0
+            else:
+                logits, new_kv, n_new = self._run_suffix(
+                    r.tokens[prefix_len:], pk, pv, prefix_len, keep)
+                kv_from = prefix_len
         # split fresh KV into block payloads and insert (suffix discard:
         # only up to ``keep`` tokens total)
-        with self.lock:
+        with self._phase("kv_insert"), self.lock:
             if prefix_len:
                 self.cache.unpin(r.chain, use_blocks)
             if not resident:
@@ -973,7 +997,7 @@ class PrefillOnlyEngine:
         # cache probe + pin under the lock; the forward runs outside it so
         # router/admission probes never block on compute (solo-path rule)
         prefs: List[Tuple[int, List, int]] = []
-        with self.lock:
+        with self._phase("cache_match"), self.lock:
             for r in batch:
                 matched = self._match_restoring(r.chain, rid=r.req_id)
                 plen = self._usable_prefix_len(r.n_input, matched)
@@ -1028,11 +1052,9 @@ class PrefillOnlyEngine:
         # padding prefix slots get a huge position: the causal mask
         # (suffix pos >= prefix pos) kills them
         ppos = np.full((Nb, pmax), PAD_POS, np.int32)
-        pk_rows: List = []
-        pv_rows: List = []
         off = cum = 0
         for n, r in enumerate(batch):
-            plen, payloads, _ = prefs[n]
+            plen = prefs[n][0]
             L = suffixes[n]
             toks[0, off:off + L] = r.tokens[plen:]
             segs[0, off:off + L] = n
@@ -1044,8 +1066,6 @@ class PrefillOnlyEngine:
             inv_idx[off:off + L] = n * smax + np.arange(L)
             if pmax:
                 ppos[n, :plen] = np.arange(plen)
-                pk_rows.append((plen, [p[0] for p in payloads]))
-                pv_rows.append((plen, [p[1] for p in payloads]))
             off += L
             cum += keeps[n]
         last_idx[N:] = last_idx[N - 1]
@@ -1058,16 +1078,27 @@ class PrefillOnlyEngine:
         self._last_shape = {"S": S, "Nb": Nb if pmax else 0, "smax": smax,
                             "pmax": pmax, "K": K}
         if pmax:
-            logits, kv = self._run_packed_hit(
-                S, Nb, smax, pmax, K, toks, pos, last_idx, kv_idx,
-                seg_qidx, inv_idx, ppos, pk_rows, pv_rows)
+            # look the program up first: a new shape marks the step as
+            # compiling (watchdog-exempt) before the gather runs
+            fn = self._packed_hit_fn((S, Nb, smax, pmax, K))
+            with self._phase("kv_gather"):
+                pk = self._gather_prefix(prefs, 0, Nb, pmax)
+                pv = self._gather_prefix(prefs, 1, Nb, pmax)
+            with self._phase("dispatch"):
+                logits, kv = fn(
+                    self.params, jnp.asarray(toks), jnp.asarray(pos),
+                    jnp.asarray(last_idx), pk, pv, jnp.asarray(ppos),
+                    jnp.asarray(seg_qidx), jnp.asarray(inv_idx),
+                    jnp.asarray(kv_idx))
+                logits = logits[:N]
         else:
-            logits, kv = self._run_packed_miss(S, K, toks, segs, pos,
-                                               last_idx, kv_idx)
-        logits = logits[:N]
+            with self._phase("dispatch"):
+                logits, kv = self._run_packed_miss(S, K, toks, segs, pos,
+                                                   last_idx, kv_idx)
+                logits = logits[:N]
         now = time.perf_counter()
         cum = 0
-        with self.lock:
+        with self._phase("kv_insert"), self.lock:
             for n, r in enumerate(batch):
                 plen, _, _ = prefs[n]
                 if plen:
@@ -1085,6 +1116,28 @@ class PrefillOnlyEngine:
                                       payloads=payloads_all)
                 cum += keeps[n]
         return logits
+
+    def _gather_prefix(self, prefs: List[Tuple[int, List, int]], part: int,
+                       Nb: int, pmax: int) -> jax.Array:
+        """The pinned per-block prefix payloads (``part`` 0 = K, 1 = V) of
+        each packed segment as one batched (L, Nb, pmax, KV, hd) buffer: row
+        n is segment n's prefix, zero-padded; rows past the segments and
+        segments with no prefix are zeros."""
+        zero_row = jnp.zeros((self.cfg.num_layers, 1, pmax,
+                              self.cfg.num_kv_heads, self.cfg.head_dim),
+                             jnp.dtype(self.cfg.dtype))
+        out = []
+        for plen, payloads, _ in prefs:
+            if not payloads:
+                out.append(zero_row)
+                continue
+            buf = jnp.concatenate([p[part] for p in payloads], axis=2)
+            if plen < pmax:
+                buf = jnp.pad(buf, ((0, 0), (0, 0), (0, pmax - plen),
+                                    (0, 0), (0, 0)))
+            out.append(buf)
+        out += [zero_row] * (Nb - len(prefs))
+        return jnp.concatenate(out, axis=1)
 
     def _run_packed_miss(self, S: int, K: int, toks, segs, pos, last_idx,
                          kv_idx):
@@ -1105,19 +1158,16 @@ class PrefillOnlyEngine:
             self.params, jnp.asarray(toks), jnp.asarray(segs),
             jnp.asarray(pos), jnp.asarray(last_idx), jnp.asarray(kv_idx))
 
-    def _run_packed_hit(self, S: int, Nb: int, smax: int, pmax: int, K: int,
-                        toks, pos, last_idx, kv_idx, seg_qidx, inv_idx,
-                        ppos, pk_rows, pv_rows):
-        """Packed prefix-hit forward: assemble the pinned per-block prefix
-        payloads into the batched (L, Nb, pmax, KV, hd) buffer (row n =
-        segment n's prefix, zero-padded) and run
-        ``prefill_packed_with_prefix``."""
-        key = (S, Nb, smax, pmax, K)
+    def _packed_hit_fn(self, key: Tuple[int, int, int, int, int]):
+        """The jitted packed prefix-hit forward for ``key`` = (S, Nb, smax,
+        pmax, K): ``prefill_packed_with_prefix`` over the batched (L, Nb,
+        pmax, KV, hd) prefix buffers of ``_gather_prefix``."""
         self._last_jit = ("packed_hit", key,
                           key not in self._packed_hit_fns)
         if key not in self._packed_hit_fns:
             self._step_compiled = True
             cfg = self.cfg
+            K = key[-1]
 
             @jax.jit
             def fn(params, toks, pos, last_idx, pk, pv, ppos, seg_qidx,
@@ -1128,34 +1178,7 @@ class PrefillOnlyEngine:
                     kv_indices=kv_idx if K else None)
 
             self._packed_hit_fns[key] = fn
-
-        zero_row = jnp.zeros((self.cfg.num_layers, 1, pmax,
-                              self.cfg.num_kv_heads, self.cfg.head_dim),
-                             jnp.dtype(self.cfg.dtype))
-
-        def assemble(rows):
-            # rows: per segment (plen, per-block (L, 1, bs, KV, hd)
-            # payloads); -> the batched (L, Nb, pmax, KV, hd) buffer
-            out = []
-            for plen, parts in rows:
-                if not parts:
-                    out.append(zero_row)
-                    continue
-                buf = jnp.concatenate(parts, axis=2)
-                if plen < pmax:
-                    buf = jnp.pad(buf, ((0, 0), (0, 0), (0, pmax - plen),
-                                        (0, 0), (0, 0)))
-                out.append(buf)
-            out += [zero_row] * (Nb - len(rows))
-            return jnp.concatenate(out, axis=1)
-
-        pk = assemble(pk_rows)
-        pv = assemble(pv_rows)
-        return self._packed_hit_fns[key](
-            self.params, jnp.asarray(toks), jnp.asarray(pos),
-            jnp.asarray(last_idx), pk, pv, jnp.asarray(ppos),
-            jnp.asarray(seg_qidx), jnp.asarray(inv_idx),
-            jnp.asarray(kv_idx))
+        return self._packed_hit_fns[key]
 
     def _run_suffix(self, tokens, pk, pv, prefix_len: int, keep: int):
         S = _bucket(len(tokens), self.ecfg.suffix_buckets)

@@ -5,7 +5,7 @@ feasibility, watchdog deadlines, brownout escalation — is derived from the
 JCT predictor, yet until this module nothing measured how accurate those
 predictions actually were, and no per-request record explained *where* a
 slow request spent its time (queue vs batch-formation vs jit-compile vs
-compute vs retry). Three pieces close that gap:
+compute vs retry). Four pieces close that gap:
 
   ``SpanTracer``
       a thread-safe, bounded (ring-buffer), monotonic-clock span tracer.
@@ -24,7 +24,14 @@ compute vs retry). Three pieces close that gap:
       per-engine-step pack composition: S/N/smax/pmax/K, padding-waste
       fraction, jit key + compile hit/miss, predicted JCT vs measured wall
       time — the hidden variables behind prefill throughput (Prepacking,
-      arXiv 2404.09529) made observable per batch.
+      arXiv 2404.09529) made observable per batch — and the host seconds
+      of each engine phase of the step (``phases``).
+
+  ``Phase``
+      one host phase of an engine step on two clocks at once: a profiler
+      span ``engine.<phase>`` (``jax.profiler.TraceAnnotation``, on the
+      device trace's host clock) and its ``perf_counter`` seconds in the
+      step's ``BatchRecord.phases``.
 
   ``JCTCalibrationMonitor``
       online residual tracking of the JCT predictor per bucket class, with
@@ -51,6 +58,8 @@ import time
 from collections import deque
 from typing import Dict, List, Optional, Sequence, Tuple
 
+from jax.profiler import TraceAnnotation
+
 
 @dataclasses.dataclass
 class BatchRecord:
@@ -73,6 +82,8 @@ class BatchRecord:
     compiled: bool = False       # this step compiled a fresh jit shape
     predicted_jct: float = 0.0   # model prediction made BEFORE execution
     wall: float = 0.0            # measured forward wall time
+    phases: Dict[str, float] = dataclasses.field(default_factory=dict)
+                                 # host seconds per engine phase (Phase)
 
     @property
     def padding_waste(self) -> float:
@@ -88,6 +99,35 @@ class BatchRecord:
         d["jit_key"] = list(self.jit_key)
         d["padding_waste"] = self.padding_waste
         return d
+
+
+class Phase:
+    """One host phase of an engine step, timed on two clocks.
+
+    Entering opens the profiler span ``engine.<name>``, so the phase lands
+    on the profiler's host plane beside the device's programs; leaving adds
+    the phase's ``perf_counter`` seconds to ``into[name]`` (the step's
+    ``BatchRecord.phases``). Always on: with no profiler running the span
+    costs about a microsecond. Open one per phase of a step, never inside a
+    per-block, per-token or per-request loop.
+    """
+
+    __slots__ = ("into", "name", "_span", "_t0")
+
+    def __init__(self, into: Dict[str, float], name: str):
+        self.into = into
+        self.name = name
+
+    def __enter__(self) -> "Phase":
+        self._span = TraceAnnotation("engine." + self.name)
+        self._span.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        dt = time.perf_counter() - self._t0
+        self._span.__exit__(*exc)
+        self.into[self.name] = self.into.get(self.name, 0.0) + dt
 
 
 class _Trace:
@@ -451,7 +491,7 @@ class SpanTracer:
                          ("step", "n_requests", "req_ids", "computed_tokens",
                           "padded_tokens", "padding_waste", "S", "Nb",
                           "smax", "pmax", "K", "jit_path", "jit_key",
-                          "compiled", "predicted_jct", "wall")}})
+                          "compiled", "predicted_jct", "wall", "phases")}})
         return {"traceEvents": events, "displayTimeUnit": "ms"}
 
     def stats(self) -> Dict:
