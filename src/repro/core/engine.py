@@ -77,6 +77,12 @@ from repro.runtime.hw import chip_for
 from repro.serving.tracing import BatchRecord, JCTCalibrationMonitor, Phase
 
 
+# Cache blocks one split program cuts from a kept-KV output (a 17k-token
+# miss, about 1,073 blocks, takes 17 dispatches). The last call's surplus,
+# at most SPLIT_BLOCKS - 1 blocks, is dropped as it returns.
+SPLIT_BLOCKS = 64
+
+
 @dataclasses.dataclass
 class EngineConfig:
     policy: str = "srjf_calibrated"
@@ -188,6 +194,7 @@ class PrefillOnlyEngine:
         self._suffix_fns: Dict[Tuple[int, int, int], callable] = {}
         self._packed_fns: Dict[Tuple[int, int], callable] = {}
         self._packed_hit_fns: Dict[Tuple[int, int, int], callable] = {}
+        self._split_fns: Dict[Tuple, callable] = {}
         self._last_step_ids: List[int] = []    # all requests served by the
                                                # most recent step()
         self._inflight: List[int] = []         # popped by step(), not yet in
@@ -204,6 +211,8 @@ class PrefillOnlyEngine:
         self.pack_skew_splits = 0      # packs closed early because the best
                                        # remaining candidate's padding
                                        # externality exceeded its benefit
+        self.kv_insert_blocks = 0      # cache blocks cut from fresh KV
+        self.kv_insert_programs = 0    # split programs dispatched for them
         self._formed_cost = 0.0        # shape-priced cost of the last pack
         self._step_compiled = False    # step hit a fresh jit shape
         # result validation: a forward can emit non-finite logits (bad
@@ -935,12 +944,9 @@ class PrefillOnlyEngine:
             if not resident:
                 n_insertable = self.kv.insertable_tokens(keep, kv_from, n_new)
                 n_blocks_new = n_insertable // bs
-                payloads_all = self.cache.match_payloads(
-                    r.chain)[:use_blocks]
-                for b in range(n_blocks_new):
-                    k_b = new_kv["k"][:, :, b * bs:(b + 1) * bs]
-                    v_b = new_kv["v"][:, :, b * bs:(b + 1) * bs]
-                    payloads_all.append((k_b, v_b))
+                payloads_all = (
+                    self.cache.match_payloads(r.chain)[:use_blocks]
+                    + self._split_blocks(new_kv, 0, n_blocks_new))
                 self.cache.insert(r.chain, kv_from + n_blocks_new * bs,
                                   now=time.perf_counter(),
                                   payloads=payloads_all)
@@ -1097,24 +1103,23 @@ class PrefillOnlyEngine:
                                                    last_idx, kv_idx)
                 logits = logits[:N]
         now = time.perf_counter()
-        cum = 0
         with self._phase("kv_insert"), self.lock:
+            # the keep windows are whole blocks laid end to end from 0 in
+            # kv_idx order, so one split cuts every segment's blocks
+            fresh = (self._split_blocks(kv, 0, sum(keeps) // bs)
+                     if kv is not None else [])
             for n, r in enumerate(batch):
                 plen, _, _ = prefs[n]
                 if plen:
                     self.cache.unpin(r.chain, plen // bs)
+                mine, fresh = fresh[:keeps[n] // bs], fresh[keeps[n] // bs:]
                 # keeps[n] == 0: nothing insertable (or already resident —
                 # the probe's match walk refreshed its LRU standing)
                 if kv is not None and keeps[n]:
                     payloads_all = (self.cache.match_payloads(
                         r.chain)[:plen // bs] if plen else [])
-                    for b in range(keeps[n] // bs):
-                        lo = cum + b * bs
-                        payloads_all.append((kv["k"][:, :, lo:lo + bs],
-                                             kv["v"][:, :, lo:lo + bs]))
                     self.cache.insert(r.chain, plen + keeps[n], now=now,
-                                      payloads=payloads_all)
-                cum += keeps[n]
+                                      payloads=payloads_all + mine)
         return logits
 
     def _gather_prefix(self, prefs: List[Tuple[int, List, int]], part: int,
@@ -1138,6 +1143,57 @@ class PrefillOnlyEngine:
             out.append(buf)
         out += [zero_row] * (Nb - len(prefs))
         return jnp.concatenate(out, axis=1)
+
+    def _split_blocks(self, kv: Dict, start: int,
+                      n_blocks: int) -> List[Tuple[jax.Array, jax.Array]]:
+        """Cut ``n_blocks`` cache-block payloads ``(k_b, v_b)`` out of a
+        kept-KV output ``{"k", "v"}`` of shape (L, 1, T, KV, hd): block b is
+        tokens ``start + b*bs`` to ``start + (b+1)*bs``.
+
+        One jitted program cuts G = min(SPLIT_BLOCKS, T // bs) blocks at a
+        traced start, so its key is the array's shape alone (not the
+        per-request block count) and a cut takes ceil(n_blocks / G)
+        dispatches. Each block is its own dynamic slice: a wanted block lies
+        inside the array and never clamps; only the surplus blocks of the
+        last call may run past the end, and they are dropped. The programs
+        queue behind the forward; nothing here waits on the device."""
+        if n_blocks <= 0:
+            return []
+        bs = self.ecfg.block_size
+        shape, dtype = kv["k"].shape, kv["k"].dtype
+        if start < 0 or start + n_blocks * bs > shape[2]:
+            raise ValueError(f"blocks {start}+{n_blocks}x{bs} outside the "
+                             f"{shape[2]}-token KV")
+        G = min(SPLIT_BLOCKS, shape[2] // bs)
+        key = (shape, dtype, G)
+        if key not in self._split_fns:
+            self._step_compiled = True
+
+            @jax.jit
+            def fn(k, v, lo):
+                return tuple(jax.lax.dynamic_slice_in_dim(x, lo + j * bs, bs,
+                                                          axis=2)
+                             for x in (k, v) for j in range(G))
+
+            self._split_fns[key] = fn
+        fn = self._split_fns[key]
+        out: List[Tuple[jax.Array, jax.Array]] = []
+        for b in range(0, n_blocks, G):
+            parts = fn(kv["k"], kv["v"], np.int32(start + b * bs))
+            m = min(G, n_blocks - b)
+            out += zip(parts[:m], parts[G:G + m])
+        programs = -(-n_blocks // G)
+        self.kv_insert_blocks += n_blocks
+        self.kv_insert_programs += programs
+        if self.metrics is not None:
+            inst = self.instance_name
+            self.metrics.counter(
+                "kv_insert_blocks", inst,
+                help="cache blocks cut from fresh KV").inc(n_blocks)
+            self.metrics.counter(
+                "kv_insert_programs", inst,
+                help="split programs dispatched to cut them").inc(programs)
+        return out
 
     def _run_packed_miss(self, S: int, K: int, toks, segs, pos, last_idx,
                          kv_idx):
@@ -1255,6 +1311,8 @@ class PrefillOnlyEngine:
             "packed_requests": self.packed_requests,
             "packed_hit_requests": self.packed_hit_requests,
             "pack_skew_splits": self.pack_skew_splits,
+            "kv_insert_blocks": self.kv_insert_blocks,
+            "kv_insert_programs": self.kv_insert_programs,
             "nonfinite_results": self.nonfinite_results,
             # fraction of paid forward slots that were padding/cache slack
             "padding_waste": 1.0 - (self.total_tokens

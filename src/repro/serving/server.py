@@ -412,6 +412,8 @@ class AsyncServer:
         # must, so _start_worker can hand it over
         self._events.setdefault(name, threading.Event()).set()
         if early is not None:        # worker finished before we registered
+            if isinstance(early, dict):
+                self.metrics.counter("requests_served", name).inc()
             if ctx is not None:
                 sp.finish(ctx, f"rejected:{early.reason}"
                           if isinstance(early, Rejected) else "delivered")
@@ -466,7 +468,7 @@ class AsyncServer:
           "delivered"  the open future was resolved
           "parked"     submit() hasn't registered the future yet — the
                        result waits in ``_early`` and resolves at
-                       registration (counts as delivered for telemetry)
+                       registration (counted as served when claimed)
           "dropped"    ``rid`` was confiscated for retry (crash/watchdog/
                        quarantine) — a late result must NOT double-resolve
                        the future its replacement now owns
@@ -642,6 +644,8 @@ class AsyncServer:
         self.metrics.counter("requests_retried", new_name).inc()
         self._events.setdefault(new_name, threading.Event()).set()
         if early is not None:            # peer served before the re-key
+            if isinstance(early, dict):
+                self.metrics.counter("requests_served", new_name).inc()
             with self._lock:
                 self._outstanding -= 1
                 self._cond.notify_all()
@@ -900,7 +904,10 @@ class AsyncServer:
                     # step dawdled — its replacement owns the future now
                     m.counter("late_results_dropped", name).inc()
                     continue
-                m.counter("requests_served", name).inc()
+                if status == "delivered":
+                    # a parked result counts once submit() or a retry
+                    # claims it: an orphan nobody claims was never served
+                    m.counter("requests_served", name).inc()
                 m.histogram("latency_seconds", name).observe(res["latency"])
                 if (self.admission is not None
                         and res.get("deadline") is not None):
