@@ -2,8 +2,8 @@
 
 A served result is one request's scores over its label tokens (the engine
 renormalises the last position's logits over the label set). Against the
-reference's logits of the same labels at the same position, two numbers are
-read over the sampled requests, each the widest over the sample:
+reference's logits of the same labels at the same position, each request
+gives two numbers:
 
 ``logodds_err``
     max over labels of |served log-score - reference logit|, both centred
@@ -12,6 +12,13 @@ read over the sampled requests, each the widest over the sample:
     reference best label logit - reference logit of the label the system
     served: how far below the reference's choice the served choice lies
     (0 when both pick the same label).
+
+Over the sample, each is read as its widest, and ``logodds_rms`` is the
+root mean square of ``logodds_err``. A cell's limits name the numbers it
+compares. With two labels a request's error is one signed number whose
+size swings from request to request, so the widest of eight overlaps
+between the program and a control one precision below; their root mean
+square does not (``PERF.md``).
 
 For the control, the same numbers are read from the control's logits in
 place of the served scores (``label_gap`` then takes the label the control
@@ -24,7 +31,7 @@ from typing import Dict, List, Sequence
 
 import numpy as np
 
-NUMBERS = ("logodds_err", "label_gap")
+NUMBERS = ("logodds_err", "label_gap", "logodds_rms")
 
 
 def _centred(v: np.ndarray) -> np.ndarray:
@@ -52,8 +59,15 @@ def control_numbers(ctrl: np.ndarray, ref: np.ndarray) -> Dict[str, float]:
             "label_gap": float(ref.max() - ref[int(np.argmax(ctrl))])}
 
 
-def widest(rows: List[Dict[str, float]]) -> Dict[str, float]:
-    return {k: max((r[k] for r in rows), default=math.inf) for k in NUMBERS}
+def summary(rows: List[Dict[str, float]]) -> Dict[str, float]:
+    """The numbers of a sample: the widest of each request's two, and the
+    root mean square of ``logodds_err`` (inf for an empty sample)."""
+    out = {k: max((r[k] for r in rows), default=math.inf)
+           for k in ("logodds_err", "label_gap")}
+    errs = [r["logodds_err"] for r in rows]
+    out["logodds_rms"] = (math.sqrt(sum(e * e for e in errs) / len(errs))
+                          if errs else math.inf)
+    return out
 
 
 def verdict(numbers: Dict[str, float], limits: Dict[str, float],
@@ -78,16 +92,18 @@ def is_correct(v: Dict[str, Dict]) -> bool:
 
 
 def sample(records: List[Dict], n: int, seed: int,
-           key_path: str = "path") -> List[Dict]:
+           keys: Sequence[str] = ("path", "instance")) -> List[Dict]:
     """Requests to compare, drawn from the seed: the longest, the longest of
-    each step path that served any, then random others up to ``n``."""
+    each step path and of each replica that served any, then random others
+    up to ``n``."""
     rng = np.random.default_rng([int(seed), 99])
     by_len = sorted(records, key=lambda r: -r["n_input"])
     chosen: List[Dict] = by_len[:1]
-    for path in sorted({r[key_path] for r in records}):
-        best = next(r for r in by_len if r[key_path] == path)
-        if best not in chosen:
-            chosen.append(best)
+    for key in keys:
+        for value in sorted({r[key] for r in records}):
+            best = next(r for r in by_len if r[key] == value)
+            if best not in chosen:
+                chosen.append(best)
     rest = [r for r in records if r not in chosen]
     for i in rng.permutation(len(rest))[:max(0, n - len(chosen))]:
         chosen.append(rest[int(i)])

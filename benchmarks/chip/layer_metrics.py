@@ -11,6 +11,8 @@ from __future__ import annotations
 
 from typing import Optional
 
+import phases
+
 
 def hit_share(ctx) -> Optional[float]:
     n = sum(r["n_input"] for r in ctx.requests)
@@ -58,3 +60,13 @@ def idle_in_steps(ctx) -> Optional[float]:
 
 def compiles(ctx) -> float:
     return float(ctx.compiles[0])
+
+
+def phase_share(ctx, key: str) -> Optional[float]:
+    """``phases.shares`` of the reduced trace: a class of idle in steps, %
+    of step time, or ``programs_per_step``. A phase that ran with no idle
+    under it reads 0; nothing is read from a trace without engine steps."""
+    t = ctx.trace
+    if t is None or not t["step_s"] or not t["engine_steps"]:
+        return None
+    return phases.shares(t).get(key, 0.0)

@@ -18,9 +18,11 @@ into disjoint classes, by interval intersection over every gap:
 ``idle_outside_phases_s``  the rest
 
 The three sum to idle in steps. Device times are the mean over device
-planes, as ``trace_reduce.reduce`` gives ``busy_in_steps_s``.
+planes, as ``trace_reduce.reduce`` gives ``busy_in_steps_s``, which merges
+this module's keys into its result; ``shares`` turns them into the
+per-layer metrics' percentages.
 
-Reduce a saved trace with both reductions merged:
+Reduce a saved trace:
 
     python3 benchmarks/chip/phases.py <trace.xplane.pb>
 """
@@ -99,8 +101,7 @@ def reduce(planes: List[Dict]) -> Optional[Dict]:
     phase: Dict[str, int] = defaultdict(int)
     for p in devices:
         off = T.clock_offset(p, host)
-        busy = T.union(T.clip([(s, s + d) for _, s, d in T._device_ops(p, off)],
-                              w0, w1))
+        busy = T.busy(p, off, w0, w1)
         mods = [(s + off, s + off + d) for ln in p["lines"]
                 if ln["name"] == T.MODULES_LINE for _, s, d in ln["events"]]
         programs += sum(1 for a, _ in mods if w0 <= a < w1)
@@ -147,7 +148,6 @@ def main(argv: Sequence[str]) -> int:
     if red is None:
         print("no bench_window span or no device plane", file=sys.stderr)
         return 1
-    red.update(reduce(planes))
     if red["step_s"]:
         red["shares"] = shares(red)
     print(json.dumps(red))

@@ -6,15 +6,19 @@
                             readings they were set from
 ``metrics/<metric>.py``     a per-layer metric: ``read(ctx)`` returns a
                             number, or None when it finds nothing to read
+``arch/<arch>.py``          the architecture a configuration names with
+                            ``"arch"``: its seeded parameter tree, its plain
+                            reference and fp8 control, its operation count
 
-A new configuration, mix or metric is a new file and a new entry in
-``BENCHMARK.json``; nothing here changes.
+A new configuration, architecture, mix or metric is a new file and a new
+entry in ``BENCHMARK.json``; nothing here changes.
 """
 from __future__ import annotations
 
 import importlib.util
 import json
 from pathlib import Path
+from types import ModuleType
 from typing import Callable, Dict, List, Optional
 
 HERE = Path(__file__).resolve().parent
@@ -24,7 +28,7 @@ class Registry:
     def __init__(self, bench: Dict, base: Path = HERE):
         self.bench = bench
         self.base = Path(base)
-        self._metrics: Dict[str, Callable] = {}
+        self._modules: Dict[str, ModuleType] = {}
 
     @classmethod
     def from_file(cls, path: Path, base: Path = HERE) -> "Registry":
@@ -51,18 +55,28 @@ class Registry:
     def limits(self, cell: str) -> Dict:
         return self._json("limits", cell)
 
-    def metric(self, name: str) -> Callable:
-        if name not in self._metrics:
-            path = self.base / "metrics" / f"{name}.py"
+    def _module(self, kind: str, name: str) -> ModuleType:
+        path = self.base / kind / f"{name}.py"
+        if str(path) not in self._modules:
             spec = importlib.util.spec_from_file_location(
-                "bench_metric_" + name.replace(".", "_").replace("-", "_"),
+                f"bench_{kind}_" + name.replace(".", "_").replace("-", "_"),
                 path)
             if spec is None or not path.is_file():
-                raise FileNotFoundError(f"no metric reader {path}")
+                raise FileNotFoundError(f"no {kind} module {path}")
             mod = importlib.util.module_from_spec(spec)
             spec.loader.exec_module(mod)
-            self._metrics[name] = mod.read
-        return self._metrics[name]
+            self._modules[str(path)] = mod
+        return self._modules[str(path)]
+
+    def metric(self, name: str) -> Callable:
+        """The reader of a per-layer metric: ``read(ctx)``."""
+        return self._module("metrics", name).read
+
+    def arch(self, name: str) -> ModuleType:
+        """An architecture's module: ``make_params(m, w, seed, dtype)``,
+        ``label_logits(cfg, seed, prompts, labels, quant=None)`` and
+        ``request_flops(m, computed, cached)``."""
+        return self._module("arch", name)
 
     def _reported(self, entry: Dict, cell: str,
                   e2e_of_cell: Optional[List[str]] = None) -> bool:

@@ -1,9 +1,11 @@
 """The system under test, built as ``launch/serve.py`` builds its thread-mode
 served path, and the closed loops that drive it through ``AsyncServer.submit``.
 
-One engine on one chip: ``InstancePool`` -> engine with the paper's JCT
-profile run -> ``AsyncServer`` with the least-backlog router, MIL admission
-from the engine's ``MemoryModel``, idempotent retry and the JCT-deadline
+One one-chip replica per device given: ``InstancePool`` with engines
+``inst0..inst{n-1}``, each committing its own copy of one parameter tree to
+its device and running the paper's JCT profile, as ``make_pool`` places
+them -> ``AsyncServer`` with the configuration's router, MIL admission from
+the engines' ``MemoryModel``, idempotent retry and the JCT-deadline
 watchdog, as ``serve_trace`` sets them. Options that a deployment sets (the
 prefix-cache size, the watchdog's floor, the profiled lengths, engine
 options) come from the configuration's file.
@@ -76,25 +78,36 @@ class Sent:
 
 
 class Served:
-    def __init__(self, cfg: Dict, params, device, engine_cls=TracedEngine):
+    """``devices[i]`` holds replica ``inst{i}`` (a device may hold several
+    off the chip)."""
+
+    def __init__(self, cfg: Dict, params, devices: Sequence,
+                 engine_cls=TracedEngine):
         mcfg = model_config(cfg)
         srv = cfg["server"]
         self.cache_tokens = int(cfg["cache_tokens"])
         ecfg = engine_config(cfg, self.cache_tokens)
+        names = [f"inst{i}" for i in range(len(devices))]
+        device_of = dict(zip(names, devices))
+        self.built_s: Dict[str, float] = {}
 
         def make_engine(name: str) -> PrefillOnlyEngine:
+            t = time.perf_counter()
             eng = engine_cls(mcfg, params, dataclasses.replace(ecfg),
-                             device=device)
+                             device=device_of[name])
             eng.profile(tuple(srv["profile_lengths"]))
+            self.built_s[name] = time.perf_counter() - t
             return eng
 
         self.pool = InstancePool(make_engine)
-        self.pool.scale_to(["inst0"])
-        self.engine = self.pool.engines["inst0"]
-        kv_keep = self.engine.ecfg.kv_keep_tokens
+        self.pool.scale_to(names)
+        self.engines: Dict[str, PrefillOnlyEngine] = {
+            n: self.pool.engines[n] for n in names}
+        first = self.engines[names[0]]
+        kv_keep = first.ecfg.kv_keep_tokens
         ctrl = AdmissionController(
             max_input_tokens=None,
-            memory_model=MemoryModel(self.engine.cfg, self.engine.chip),
+            memory_model=MemoryModel(first.cfg, first.chip),
             kv_keep=None if kv_keep >= 10**9 else kv_keep)
         self.tracer = SpanTracer(capacity=1 << 16, batch_capacity=1 << 16)
         self.server = AsyncServer(

@@ -108,12 +108,16 @@ def test_comparison_numbers():
     assert good["logodds_err"] < 1e-9 and good["label_gap"] == 0.0
     bad = check.served_numbers({5: p[1], 6: p[0]}, 5, [5, 6], ref)
     assert bad["label_gap"] == 2.0 and bad["logodds_err"] == pytest.approx(2)
-    v = check.verdict(check.widest([good]), {"logodds_err": 0.1,
+    v = check.verdict(check.summary([good]), {"logodds_err": 0.1,
                                              "label_gap": 0.1}, 1, 0)
     assert check.is_correct(v)
     assert not check.is_correct(check.verdict(
-        check.widest([good, bad]), {"logodds_err": 0.1, "label_gap": 0.1},
+        check.summary([good, bad]), {"logodds_err": 0.1, "label_gap": 0.1},
         2, 0))
+    assert check.summary([good, bad])["logodds_rms"] == pytest.approx(
+        2 ** 0.5)
+    assert not check.is_correct(check.verdict(
+        check.summary([good, bad]), {"logodds_rms": 1.0}, 2, 0))
 
 
 def _plane(name, lines):

@@ -7,7 +7,8 @@ The benchmark marks its measured window with a host span named
 Device time is the union of the operation events on each device plane's
 "XLA Ops" line (every line of the plane where that line is missing), moved
 onto the host clock by ``clock_offset``; an operation is named by its HLO
-instruction name ("%fusion.28").
+instruction name ("%fusion.28"). The partition of idle inside steps by
+host phase (``phases.reduce``) is merged into the result.
 """
 from __future__ import annotations
 
@@ -15,6 +16,8 @@ import bisect
 import re
 from collections import defaultdict
 from typing import Dict, List, Optional, Sequence, Tuple
+
+import numpy as np
 
 WINDOW = "bench_window"
 STEP = "bench_step"
@@ -26,18 +29,20 @@ Interval = Tuple[int, int]
 
 
 def load(path: str) -> List[Dict]:
-    """An ``.xplane.pb`` as plain planes -> lines -> (name, start, dur) ns."""
+    """An ``.xplane.pb`` as plain planes -> lines -> (name, start, dur) ns.
+    Of a device plane that has an "XLA Ops" line only that line and "XLA
+    Modules" are read: the reduction uses no other."""
     from jax.profiler import ProfileData
     pd = ProfileData.from_file(path)
     planes = []
     for plane in pd.planes:
-        lines = []
-        for line in plane.lines:
-            lines.append({"name": line.name,
-                          "events": [(e.name, int(e.start_ns),
-                                      int(e.duration_ns))
-                                     for e in line.events]})
-        planes.append({"name": plane.name, "lines": lines})
+        lines = list(plane.lines)
+        if is_device(plane.name) and any(ln.name == OPS_LINE for ln in lines):
+            lines = [ln for ln in lines if ln.name in (OPS_LINE, MODULES_LINE)]
+        planes.append({"name": plane.name, "lines": [
+            {"name": ln.name,
+             "events": [(e.name, int(e.start_ns), int(e.duration_ns))
+                        for e in ln.events]} for ln in lines]})
     return planes
 
 
@@ -90,11 +95,28 @@ def gaps(busy: Sequence[Interval], lo: int, hi: int) -> List[Interval]:
     return out
 
 
-def _device_ops(plane: Dict, shift: int = 0) -> List[Tuple[str, int, int]]:
+def _op_lines(plane: Dict) -> List[Dict]:
     ops = [ln for ln in plane["lines"] if ln["name"] == OPS_LINE]
-    lines = ops or plane["lines"]
-    return [(n.split(" = ")[0], s + shift, d) for ln in lines
-            for n, s, d in ln["events"] if d > 0]
+    return ops or plane["lines"]
+
+
+def busy(plane: Dict, shift: int, lo: int, hi: int) -> List[Interval]:
+    """``union(clip(...))`` of the plane's operation intervals moved by
+    ``shift`` onto the host clock, in numpy: a traced window holds millions
+    of operations."""
+    ev = [e for ln in _op_lines(plane) for e in ln["events"] if e[2] > 0]
+    s = np.fromiter((e[1] for e in ev), np.int64, len(ev)) + shift
+    e = s + np.fromiter((e[2] for e in ev), np.int64, len(ev))
+    keep = (e > lo) & (s < hi)
+    s, e = np.maximum(s[keep], lo), np.minimum(e[keep], hi)
+    if not len(s):
+        return []
+    order = np.argsort(s, kind="stable")
+    s, e = s[order], e[order]
+    run = np.maximum.accumulate(e)
+    first = np.flatnonzero(np.r_[True, s[1:] > run[:-1]])
+    return list(zip(s[first].tolist(),
+                    np.r_[run[first[1:] - 1], run[-1]].tolist()))
 
 
 def clock_offset(plane: Dict, host: List[Tuple[str, int, int]],
@@ -145,10 +167,12 @@ def _host_at(t: int, lines: List[List], starts: List[List[int]],
 
 def reduce(planes: List[Dict], top: int = 10,
            max_gaps: int = 500) -> Optional[Dict]:
-    """Busy and idle numbers over the ``bench_window`` span, or None when the
-    trace holds no window or no device plane. The ``max_gaps`` longest idle
-    gaps are named by the host span open at their middle; the rest are
-    summed as "(shorter gaps)"."""
+    """Busy and idle numbers over the ``bench_window`` span, with the keys of
+    ``phases.reduce`` merged in, or None when the trace holds no window or
+    no device plane. The ``max_gaps`` longest idle gaps are named by the
+    host span open at their middle; the rest are summed as "(shorter
+    gaps)"."""
+    import phases   # it builds on this module's interval arithmetic
     host_lines = [sorted(ln["events"], key=lambda e: e[1])
                   for p in planes if not is_device(p["name"])
                   for ln in p["lines"]]
@@ -163,15 +187,19 @@ def reduce(planes: List[Dict], top: int = 10,
     busy_all: List[Interval] = []
     offsets = []
     for p in devices:
-        offsets.append(clock_offset(p, host))
-        ops = _device_ops(p, offsets[-1])
-        b = union(clip([(s, s + d) for _, s, d in ops], w0, w1))
+        off = clock_offset(p, host)
+        offsets.append(off)
+        b = busy(p, off, w0, w1)
         busy_ns.append(length(b))
         in_steps_ns.append(length(intersect(b, steps)))
         busy_all = union(busy_all + b)
-        for n, s, d in ops:
-            if s >= w0 and s + d <= w1:
-                per_op[n] += d
+        by_name: Dict[str, int] = defaultdict(int)
+        for ln in _op_lines(p):
+            for n, s, d in ln["events"]:
+                if d > 0 and s + off >= w0 and s + off + d <= w1:
+                    by_name[n] += d
+        for n, d in by_name.items():
+            per_op[n.split(" = ")[0]] += d
     n_dev = len(devices)
     step_ns = length(steps)
     idle: Dict[str, int] = defaultdict(int)
@@ -184,6 +212,7 @@ def reduce(planes: List[Dict], top: int = 10,
         idle["(shorter gaps)"] += rest
     ns = 1e-9
     return {
+        **phases.reduce(planes),
         "window_s": (w1 - w0) * ns,
         "clock_offset_s": [o * ns for o in offsets],
         "busy_s": sum(busy_ns) / n_dev * ns,

@@ -29,7 +29,7 @@ from __future__ import annotations
 import dataclasses
 import math
 from statistics import NormalDist
-from typing import Dict, List, Sequence
+from typing import Dict, List, Sequence, Tuple
 
 import numpy as np
 
@@ -62,6 +62,18 @@ def quantiles(dist: Dict, n: int) -> List[int]:
 
 def dist_bounds(dist: Dict) -> Sequence[int]:
     return int(dist["min"]), int(dist["max"])
+
+
+def ladder_bucket(n: int, ladder: Sequence[int]) -> int:
+    """Smallest rung of ``ladder`` holding ``n``, doubling past the top
+    (copied from the program's ``kv_policy.bucket``)."""
+    for s in ladder:
+        if n <= s:
+            return s
+    s = ladder[-1]
+    while s < n:
+        s *= 2
+    return s
 
 
 def _rng(seed: int, stream: str, *more: int) -> np.random.Generator:
@@ -137,19 +149,54 @@ class Mix:
                             self._body_rng)
 
     # ---- set-up -------------------------------------------------------------
-    def warm_prompts(self) -> List[Prompt]:
+    def reuse_shapes(self, step: int, ladder: Sequence[int]
+                     ) -> List[List[Tuple[int, int]]]:
+        """For each group, ``(reused, suffix)`` token counts of one request
+        per cache-hit shape first met in that group, longest reuse first.
+
+        A cache that holds a group's prefix, or only its head because its
+        tail was evicted, offers every multiple ``P`` of ``step`` up to the
+        prefix's length; the rest of the prompt, ``suffix`` tokens, runs on
+        the rung of ``ladder`` that holds it. A shape is the pair (``P``,
+        rung); each is listed once, with the longest suffix on that rung
+        that a window request of the group can have."""
+        lo, hi = dist_bounds(self.spec["body_tokens"])
+        seen, out = set(), []
+        for prefix in self.prefixes:
+            mine = []
+            for p in range(len(prefix) // step * step, 0, -step):
+                shortest, longest = len(prefix) - p + lo, len(prefix) - p + hi
+                rung = ladder_bucket(shortest, ladder)
+                while True:
+                    if (p, rung) not in seen:
+                        seen.add((p, rung))
+                        mine.append((p, min(rung, longest)))
+                    if rung >= longest:
+                        break
+                    rung = ladder_bucket(rung + 1, ladder)
+            out.append(mine)
+        return out
+
+    def warm_prompts(self, step: int, ladder: Sequence[int]) -> List[Prompt]:
         """Set-up traffic, sent one at a time before the window: for each
         group its first request (so that a steady deployment's cache holds
-        it), then a request at each end of the body-length range (both
-        cache-hit shapes); for unshared prompts, one prompt at each end of
-        the length range. Tokens differ from every window request."""
+        it), then one request for each of the group's cache-hit shapes
+        (``reuse_shapes``): the group's first ``P`` prefix tokens and
+        ``suffix`` tokens of its own, so that no window request is the
+        first to run its program, whatever the cache has evicted; for
+        unshared prompts, one prompt at each end of the length range.
+        Tokens differ from every window request."""
         lo, hi = dist_bounds(self.spec["body_tokens"])
         out = []
         if self.n_groups:
             mid = quantiles(self.spec["body_tokens"], 1)[0]
+            shapes = self.reuse_shapes(step, ladder)
             for g in range(self.n_groups):
-                for n in (mid, lo, hi):
-                    out.append(self._prompt(-1, g, n, self._warm_rng))
+                out.append(self._prompt(-1, g, mid, self._warm_rng))
+                for p, suffix in shapes[g]:
+                    tail = self._warm_rng.integers(0, self.vocab, suffix)
+                    out.append(Prompt(-1, g, self.prefixes[g][:p]
+                                      + tail.tolist()))
         else:
             for n in (lo, hi):
                 out.append(self._prompt(-1, -1, n, self._warm_rng))
