@@ -18,6 +18,9 @@ class ModelConfig:
       moe     - transformer blocks with mixture-of-experts MLP
       ssm     - Mamba2 (SSD) blocks, attention-free
       hybrid  - Mamba2 backbone + shared attention block every ``attn_every``
+                (Zamba-2), or, with ``layer_types``, a published per-layer
+                pattern of Mamba2 and attention mixers, each layer followed
+                by its own MLP (GraniteMoeHybrid)
       vlm     - dense backbone fed precomputed patch embeddings (frontend stub)
       audio   - dense backbone over codec tokens (frontend stub)
     """
@@ -53,6 +56,17 @@ class ModelConfig:
     ssm_conv_width: int = 4
     ssm_chunk: int = 256               # SSD chunk length
     attn_every: int = 0                # hybrid: shared attn block cadence
+    layer_types: Tuple[str, ...] = ()  # hybrid: "mamba" | "attention" per
+                                       # layer, as published (GraniteMoeHybrid)
+
+    # --- published multipliers (GraniteMoeHybrid; 1.0 / 0 = unused) ---
+    embedding_multiplier: float = 1.0  # token embeddings times this
+    residual_multiplier: float = 1.0   # each branch's output times this
+    logits_scaling: float = 1.0        # LM logits divided by this
+    attention_multiplier: float = 0.0  # score scale; 0 -> 1/sqrt(head_dim)
+    position_embedding: str = "rope"   # "rope" | "nope" (no positions)
+    norm_eps: float = 1e-6             # RMSNorm epsilon of the pattern
+                                       # hybrid and its Mamba gated norm
 
     # --- embeddings / io ---
     embed_inputs: bool = True          # False: inputs arrive as embeddings (vlm)
@@ -69,6 +83,18 @@ class ModelConfig:
     def __post_init__(self):
         if self.head_dim == 0 and self.num_heads > 0:
             object.__setattr__(self, "head_dim", self.d_model // self.num_heads)
+        # a configuration file gives lists; the config is hashed into jits
+        object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        if self.layer_types:
+            if len(self.layer_types) != self.num_layers:
+                raise ValueError(f"{len(self.layer_types)} layer_types for "
+                                 f"{self.num_layers} layers")
+            bad = set(self.layer_types) - {"mamba", "attention"}
+            if bad:
+                raise ValueError(f"unknown layer types {sorted(bad)}")
+        if self.position_embedding not in ("rope", "nope"):
+            raise ValueError(
+                f"unknown position embedding {self.position_embedding!r}")
 
     # ---- derived quantities ----
     @property
@@ -95,6 +121,36 @@ class ModelConfig:
     def is_moe(self) -> bool:
         return self.num_experts > 0
 
+    @property
+    def n_attention_layers(self) -> int:
+        """Layers that hold attention KV: the pattern's attention layers, or
+        Zamba-2's applications of its shared block."""
+        if self.layer_types:
+            return self.layer_types.count("attention")
+        if self.family == "ssm":
+            return 0
+        if self.family == "hybrid":
+            return max(1, self.num_layers // max(self.attn_every, 1))
+        return self.num_layers
+
+    @property
+    def n_mamba_layers(self) -> int:
+        if self.layer_types:
+            return self.layer_types.count("mamba")
+        return self.num_layers if self.has_ssm else 0
+
+    @property
+    def pattern_period(self) -> int:
+        """Length of the shortest repeating unit of ``layer_types``."""
+        t = self.layer_types
+        return next(p for p in range(1, len(t) + 1)
+                    if len(t) % p == 0 and t == t[:p] * (len(t) // p))
+
+    @property
+    def attn_head_scale(self) -> Optional[float]:
+        """The attention score scale, or None for 1/sqrt(head_dim)."""
+        return self.attention_multiplier or None
+
     def param_count(self) -> int:
         """Analytic parameter count (used by roofline + MIL model)."""
         D, F, V, L = self.d_model, self.d_ff, self.vocab_size, self.num_layers
@@ -107,6 +163,8 @@ class ModelConfig:
             mlp = mlp * self.num_experts + D * self.num_experts  # + router
             if self.shared_expert:
                 mlp += 3 * D * F
+        if self.layer_types:
+            return self._pattern_param_count()
         ssm = 0
         if self.has_ssm:
             di, N, Hs = self.d_inner, self.ssm_state, self.ssm_heads
@@ -127,6 +185,34 @@ class ModelConfig:
             per_layer = attn + mlp + norms
         return embed + lm_head + L * per_layer + D
 
+    def _pattern_param_count(self) -> int:
+        """Exact count of the layer pattern (``models.hybrid.model_defs``):
+        each layer two norms, its mixer and its MLP."""
+        D, V = self.d_model, self.vocab_size
+        H, KV, hd = self.num_heads, self.num_kv_heads, self.head_dim
+        di, N, Hs, W = self.d_inner, self.ssm_state, self.ssm_heads, \
+            self.ssm_conv_width
+        conv = di + 2 * N
+        mamba = (D * (2 * di + 2 * N + Hs) + (W + 1) * conv + 3 * Hs + di
+                 + di * D)
+        attn = D * (H * hd) + 2 * D * (KV * hd) + (H * hd) * D
+        layer = 2 * D + 3 * D * self.d_ff
+        lm_head = 0 if self.tie_embeddings else V * D
+        return (V * D + lm_head + D + self.n_mamba_layers * (layer + mamba)
+                + self.n_attention_layers * (layer + attn))
+
+    def state_bytes(self, act_bytes: int = 2) -> int:
+        """Bytes of one snapshot of the recurrent state of every Mamba
+        layer: the float32 SSD state (heads x head dim x d_state) and the
+        causal conv's last ``ssm_conv_width - 1`` inputs, in the activation
+        type."""
+        if not self.has_ssm:
+            return 0
+        ssd = self.ssm_heads * self.ssm_headdim * self.ssm_state * 4
+        conv = (self.ssm_conv_width - 1) * (self.d_inner
+                                            + 2 * self.ssm_state) * act_bytes
+        return self.n_mamba_layers * (ssd + conv)
+
     @property
     def d_ff_shared(self) -> int:
         """FFN width of the shared attention block (hybrid family)."""
@@ -144,12 +230,8 @@ class ModelConfig:
 
     def kv_bytes_per_token(self, bytes_per_el: int = 2) -> int:
         """KV-cache bytes per token across all layers (full attention view)."""
-        if self.family == "ssm":
-            return 0
-        n_attn_layers = self.num_layers
-        if self.family == "hybrid":
-            n_attn_layers = max(1, self.num_layers // max(self.attn_every, 1))
-        return n_attn_layers * 2 * self.num_kv_heads * self.head_dim * bytes_per_el
+        return (self.n_attention_layers * 2 * self.num_kv_heads
+                * self.head_dim * bytes_per_el)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -17,6 +17,7 @@ from repro.configs.zamba2_2_7b import CONFIG as _zamba2
 from repro.configs.mixtral_8x22b import CONFIG as _mixtral
 from repro.configs.llama4_scout_17b_a16e import CONFIG as _llama4
 from repro.configs.llama3_1_8b import CONFIG as _llama31
+from repro.configs.granite_4_0_h_micro import CONFIG as _granite4h
 
 ASSIGNED: Dict[str, ModelConfig] = {
     "internvl2-2b": _internvl2,
@@ -33,6 +34,7 @@ ASSIGNED: Dict[str, ModelConfig] = {
 
 EXTRA: Dict[str, ModelConfig] = {
     "llama3.1-8b": _llama31,
+    "granite-4.0-h-micro": _granite4h,
 }
 
 REGISTRY: Dict[str, ModelConfig] = {**ASSIGNED, **EXTRA}
@@ -89,6 +91,9 @@ def reduce_config(cfg: ModelConfig, **overrides) -> ModelConfig:
         small["attn_every"] = 2
     if cfg.local_global:
         small["num_layers"] = 4  # two (local, global) pairs
+    if cfg.layer_types:                  # one whole period of the pattern
+        small["num_layers"] = cfg.pattern_period
+        small["layer_types"] = cfg.layer_types[:cfg.pattern_period]
     small.update(overrides)
     small["name"] = cfg.name + "-smoke"
     return dataclasses.replace(cfg, **small)

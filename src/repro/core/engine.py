@@ -69,6 +69,7 @@ from repro.core.offload import (HostKVStore, OffloadPolicy,
                                 TieredPrefixCache)
 from repro.core.prefix_cache import PrefixCache, token_chain
 from repro.core.scheduler import Request, Scheduler
+from repro.models import hybrid as hybrid_model
 from repro.models import transformer as tfm
 from repro.models.layers import PAD_POS
 from repro.models.model import cast_params
@@ -89,9 +90,13 @@ class EngineConfig:
     lam: float = 0.05                 # starvation offset (JCT-sec per wait-sec)
     block_size: int = 16
     cache_capacity_tokens: int = 4096  # prefix-KV budget (profile run output)
+                                       # in KV tokens; a hybrid's state
+                                       # snapshot is priced at its bytes'
+                                       # worth of KV tokens
     kv_keep_tokens: int = 10**9        # suffix discard threshold (per request)
     suffix_buckets: Tuple[int, ...] = (64, 128, 256, 512, 1024, 2048)
     prefix_bucket_blocks: int = 4      # reuse granularity: 4 blocks = 64 tok
+                                       # (a hybrid's snapshot stride too)
     pack_token_budget: int = 2048      # prepacking: max COMPUTED tokens/step
     max_pack_requests: int = 16        # prepacking: max segments per step
                                        # (<=1 disables batch formation)
@@ -136,16 +141,44 @@ class EngineConfig:
 
 
 class PrefillOnlyEngine:
-    """Single-instance engine over a dense-family model (real arrays).
+    """Single-instance engine over a dense-family model, or a hybrid with a
+    published layer pattern (``ModelConfig.layer_types``), on real arrays.
 
     ``device`` (default: the first device) holds this engine's parameters and
     therefore its forwards and KV: inputs are uncommitted and follow the
     committed parameters. ``chip`` is that device's peak table, which the KV
-    tier and admission price against."""
+    tier and admission price against.
+
+    A hybrid keeps two kinds of state in one cache: its attention layers'
+    KV in every block, and at every reuse boundary (``prefix_bucket_blocks``
+    blocks, a multiple of the SSD chunk) a snapshot of its Mamba layers'
+    state in that block beside the KV. A hit resumes from the deepest
+    boundary whose block holds one. It runs packs of one: a hybrid allowed
+    to pack, or to offload, is refused here."""
 
     def __init__(self, cfg: ModelConfig, params,
                  ecfg: Optional[EngineConfig] = None, device=None):
-        assert cfg.family in ("dense", "vlm", "audio", "moe"), cfg.family
+        self.stateful = bool(cfg.layer_types)
+        if not (self.stateful
+                or cfg.family in ("dense", "vlm", "audio", "moe")):
+            raise ValueError(f"the engine serves no {cfg.family!r} model "
+                             "without a layer pattern")
+        ecfg = EngineConfig() if ecfg is None else ecfg
+        self.snap_every = (ecfg.block_size * ecfg.prefix_bucket_blocks
+                           if self.stateful else 0)
+        if self.stateful:
+            if ecfg.max_pack_requests > 1:
+                raise ValueError(
+                    "a hybrid runs packs of one: packed prefill would have "
+                    "to reset the Mamba state at each segment; set "
+                    "max_pack_requests 1")
+            if ecfg.offload:
+                raise ValueError("a hybrid's state snapshots have no host "
+                                 "tier; set offload False")
+            if self.snap_every % cfg.ssm_chunk:
+                raise ValueError(
+                    f"the reuse step {self.snap_every} is no multiple of the "
+                    f"SSD chunk {cfg.ssm_chunk}")
         self.cfg = cfg
         self.device = jax.devices()[0] if device is None else device
         self.chip = chip_for(self.device.platform, self.device.device_kind)
@@ -153,7 +186,7 @@ class PrefillOnlyEngine:
                                      self.device)
         # per-engine config: a shared default instance would alias mutable
         # state (autotune) across every engine in a pool
-        self.ecfg = ecfg = EngineConfig() if ecfg is None else ecfg
+        self.ecfg = ecfg
         # Guards queue / cache / results / jct_model. The engine is driven by
         # ONE worker thread (step) while router/server threads concurrently
         # submit, cancel, shed, and probe backlog — the forward itself runs
@@ -163,7 +196,12 @@ class PrefillOnlyEngine:
         # insert-bound decision in this file asks self.kv (kv_policy).
         self.kv = KVLifecycle(block_size=ecfg.block_size,
                               kv_keep_tokens=ecfg.kv_keep_tokens,
-                              buckets=ecfg.suffix_buckets)
+                              buckets=ecfg.suffix_buckets,
+                              state_stride=self.snap_every)
+        # capacity one snapshot occupies beside its block, in KV blocks
+        self.snapshot_units = (-(-cfg.state_bytes() // max(
+            1, ecfg.block_size * cfg.kv_bytes_per_token()))
+            if self.stateful else 0)
         if ecfg.offload:
             # hierarchical KV memory: device blocks demote to host DRAM on
             # eviction, restore on match when cheaper than recompute
@@ -195,6 +233,7 @@ class PrefillOnlyEngine:
         self._packed_fns: Dict[Tuple[int, int], callable] = {}
         self._packed_hit_fns: Dict[Tuple[int, int, int], callable] = {}
         self._split_fns: Dict[Tuple, callable] = {}
+        self._snap_fns: Dict[Tuple, callable] = {}
         self._last_step_ids: List[int] = []    # all requests served by the
                                                # most recent step()
         self._inflight: List[int] = []         # popped by step(), not yet in
@@ -241,6 +280,8 @@ class PrefillOnlyEngine:
         self._last_shape: Dict[str, int] = {}
         self._phases: Dict[str, float] = {}    # host seconds per phase of
                                                # the current step (Phase)
+        self._matched_tokens = 0               # this step's cached prefix
+        self._resumed_tokens = 0               # and reused prefix tokens
 
     # ---- profile run (paper §3.1) ------------------------------------------
     def profile(self, lengths: Sequence[int] = (64, 128, 256, 512)) -> float:
@@ -294,7 +335,7 @@ class PrefillOnlyEngine:
         budget could plausibly hold.
         """
         m, ecfg = self.jct_model, self.ecfg
-        if m.a <= 0:
+        if m.a <= 0 or self.stateful:           # a hybrid packs nothing
             return ecfg.pack_token_budget, ecfg.max_pack_requests
         max_step = ecfg.pack_inflation * m.predict(ref_len)
         floor = _bucket(ref_len, ecfg.suffix_buckets)
@@ -565,13 +606,15 @@ class PrefillOnlyEngine:
 
         A step with queued work runs inside the profiler span
         ``engine.step``, and each of its host phases (form_batch,
-        cache_match, kv_gather, dispatch, device_wait, kv_insert, score,
-        record) is a ``Phase``: a leaf span of its own and its seconds in
-        the step's ``BatchRecord.phases``. The worker polls ``step()``, so
+        cache_match, kv_gather, dispatch, device_wait, kv_insert,
+        state_insert (a hybrid's snapshots), score, record) is a
+        ``Phase``: a leaf span of its own and its seconds in the step's
+        ``BatchRecord.phases``. The worker polls ``step()``, so
         an empty queue returns before any span opens."""
         if not self.queue:
             return None
         self._phases = {}
+        self._matched_tokens = self._resumed_tokens = 0
         with jax.profiler.TraceAnnotation("engine.step"):
             return self._step()
 
@@ -665,7 +708,9 @@ class PrefillOnlyEngine:
             smax=shape.get("smax", 0), pmax=shape.get("pmax", 0),
             K=shape.get("K", 0), jit_path=path, jit_key=key,
             compiled=self._step_compiled, predicted_jct=pred,
-            wall=t_done - t0, phases=self._phases)
+            wall=t_done - t0, phases=self._phases,
+            matched_tokens=self._matched_tokens,
+            resumed_tokens=self._resumed_tokens)
         self.batch_records.append(rec)
         # compile steps are excluded from calibration for the same reason
         # they are excluded from the JCT fit: compile time is unbounded and
@@ -903,14 +948,19 @@ class PrefillOnlyEngine:
     # ---- execution -----------------------------------------------------------
     def _execute(self, r: Request) -> jax.Array:
         bs = self.ecfg.block_size
+        state0 = None
         # cache probe + pin under the lock; the forward itself runs outside
         # it so router/admission probes never block on compute
         with self.lock:
             with self._phase("cache_match"):
                 matched = self._match_restoring(r.chain, rid=r.req_id)
                 prefix_len = self._usable_prefix_len(r.n_input, matched)
+                if self.stateful:
+                    prefix_len = self._snapshot_prefix(r.chain, prefix_len)
                 use_blocks = prefix_len // bs
                 r.n_cached_at_start = prefix_len
+                self._matched_tokens += matched * bs
+                self._resumed_tokens += prefix_len
                 self.hit_tokens += prefix_len
                 self.total_tokens += r.n_input
                 self.padded_slots += prefix_len + _bucket(
@@ -928,13 +978,17 @@ class PrefillOnlyEngine:
                 with self._phase("kv_gather"):
                     pk = jnp.concatenate([p[0] for p in payloads], axis=2)
                     pv = jnp.concatenate([p[1] for p in payloads], axis=2)
+                    if self.stateful:
+                        # the boundary block's snapshot: (ssm, conv)
+                        state0 = {"ssm": payloads[-1][2],
+                                  "conv": payloads[-1][3]}
         with self._phase("dispatch"):
             if prefix_len == 0:
                 logits, new_kv, n_new = self._run_fresh(r.tokens, keep)
                 kv_from = 0
             else:
                 logits, new_kv, n_new = self._run_suffix(
-                    r.tokens[prefix_len:], pk, pv, prefix_len, keep)
+                    r.tokens[prefix_len:], pk, pv, prefix_len, keep, state0)
                 kv_from = prefix_len
         # split fresh KV into block payloads and insert (suffix discard:
         # only up to ``keep`` tokens total)
@@ -947,10 +1001,62 @@ class PrefillOnlyEngine:
                 payloads_all = (
                     self.cache.match_payloads(r.chain)[:use_blocks]
                     + self._split_blocks(new_kv, 0, n_blocks_new))
+                if not self.stateful:
+                    self.cache.insert(r.chain, kv_from + n_blocks_new * bs,
+                                      now=time.perf_counter(),
+                                      payloads=payloads_all)
+        if self.stateful and not resident:
+            with self._phase("state_insert"), self.lock:
+                units = self._attach_snapshots(new_kv, payloads_all,
+                                               use_blocks, n_blocks_new)
                 self.cache.insert(r.chain, kv_from + n_blocks_new * bs,
                                   now=time.perf_counter(),
-                                  payloads=payloads_all)
+                                  payloads=payloads_all, units=units)
         return logits
+
+    def _snapshot_prefix(self, chain: Tuple[int, ...],
+                         prefix_len: int) -> int:
+        """The deepest reuse boundary at or below ``prefix_len`` whose cache
+        block holds a state snapshot (blocks are resident: the caller
+        matched them). Snapshots leave with their blocks, so this is
+        ``prefix_len`` itself unless a boundary block lacks one."""
+        bs = self.ecfg.block_size
+        while prefix_len:
+            blk = self.cache.blocks.get(chain[prefix_len // bs - 1])
+            if blk is not None and blk.payload is not None \
+                    and len(blk.payload) == 4:
+                break
+            prefix_len -= self.snap_every
+        return prefix_len
+
+    def _attach_snapshots(self, aux: Dict, payloads: List[Tuple],
+                          first: int, n_blocks: int) -> List[int]:
+        """Put each kept snapshot of a forward's ``aux`` beside the KV of
+        the block that ends on its boundary: ``payloads[first:]`` are the
+        ``n_blocks`` fresh blocks, which start on a boundary. Returns each
+        block's capacity units for ``PrefixCache.insert``."""
+        units = [1] * len(payloads)
+        n = self.kv.snapshots_kept(n_blocks * self.ecfg.block_size)
+        if not n:
+            return units
+        ssm, conv = aux["ssm"], aux["conv"]
+        key = (ssm.shape, ssm.dtype, conv.shape, conv.dtype)
+        if key not in self._snap_fns:
+            self._step_compiled = True
+
+            @jax.jit
+            def fn(ssm, conv):
+                # (layers, 1, slots, ...) -> one (layers, 1, ...) per slot
+                return tuple((ssm[:, :, j], conv[:, :, j])
+                             for j in range(ssm.shape[2]))
+
+            self._snap_fns[key] = fn
+        per = self.ecfg.prefix_bucket_blocks
+        for j, snap in enumerate(self._snap_fns[key](ssm, conv)[:n]):
+            i = first + (j + 1) * per - 1
+            payloads[i] = tuple(payloads[i]) + snap
+            units[i] += self.snapshot_units
+        return units
 
     def _run_fresh(self, tokens: Sequence[int], keep: int = 0):
         S = _bucket(len(tokens), self.ecfg.suffix_buckets)
@@ -962,11 +1068,20 @@ class PrefillOnlyEngine:
         if key not in self._fresh_fns:
             self._step_compiled = True
             cfg = self.cfg
+            if self.stateful:
+                snap, n_snap = self.snap_every, self.kv.snapshot_slots(
+                    keep_pad)
 
-            @jax.jit
-            def fn(params, toks, last_index):
-                return tfm.prefill(params, cfg, {"tokens": toks},
-                                   kv_keep=keep_pad, last_index=last_index)
+                @jax.jit
+                def fn(params, toks, last_index):
+                    return hybrid_model.pattern_prefill(
+                        params, cfg, toks, kv_keep=keep_pad, snap_every=snap,
+                        n_snap=n_snap, last_index=last_index)
+            else:
+                @jax.jit
+                def fn(params, toks, last_index):
+                    return tfm.prefill(params, cfg, {"tokens": toks},
+                                       kv_keep=keep_pad, last_index=last_index)
 
             self._fresh_fns[key] = fn
         toks = np.zeros((1, S), np.int32)
@@ -974,7 +1089,7 @@ class PrefillOnlyEngine:
         logits, kv = self._fresh_fns[key](
             self.params, jnp.asarray(toks),
             jnp.asarray([len(tokens) - 1], jnp.int32))
-        if kv is None:
+        if not kv:
             return logits, {"k": None, "v": None}, 0
         # kv: (L, 1, keep_pad, KV, hd); valid fresh tokens = len(tokens),
         # usable budget = the caller's keep (keep_pad only pads the jit key)
@@ -1014,6 +1129,8 @@ class PrefillOnlyEngine:
                     payloads = self.cache.match_payloads(
                         r.chain)[:plen // bs]
                 prefs.append((plen, payloads, matched))
+                self._matched_tokens += matched * bs
+                self._resumed_tokens += plen
                 self.hit_tokens += plen
                 self.total_tokens += r.n_input
         suffixes = [r.n_input - p for r, (p, _, _) in zip(batch, prefs)]
@@ -1236,7 +1353,8 @@ class PrefillOnlyEngine:
             self._packed_hit_fns[key] = fn
         return self._packed_hit_fns[key]
 
-    def _run_suffix(self, tokens, pk, pv, prefix_len: int, keep: int):
+    def _run_suffix(self, tokens, pk, pv, prefix_len: int, keep: int,
+                    state0: Optional[Dict] = None):
         S = _bucket(len(tokens), self.ecfg.suffix_buckets)
         P = pk.shape[2]
         keep_new = self.kv.suffix_keep_new(keep, prefix_len, S)
@@ -1248,19 +1366,32 @@ class PrefillOnlyEngine:
         if key not in self._suffix_fns:
             self._step_compiled = True
             cfg = self.cfg
+            if self.stateful:
+                snap, n_snap = self.snap_every, self.kv.snapshot_slots(
+                    keep_pad)
 
-            @jax.jit
-            def fn(params, toks, pk, pv, last_index):
-                return tfm.prefill_with_prefix(
-                    params, cfg, {"tokens": toks}, {"k": pk, "v": pv},
-                    prefix_len=P, kv_keep=P + keep_pad, last_index=last_index)
+                @jax.jit
+                def fn(params, toks, pk, pv, last_index, state0):
+                    return hybrid_model.pattern_prefill(
+                        params, cfg, toks, kv_keep=keep_pad, snap_every=snap,
+                        n_snap=n_snap, prefix_len=P,
+                        prefix_kv={"k": pk, "v": pv}, state0=state0,
+                        last_index=last_index)
+            else:
+                @jax.jit
+                def fn(params, toks, pk, pv, last_index):
+                    return tfm.prefill_with_prefix(
+                        params, cfg, {"tokens": toks}, {"k": pk, "v": pv},
+                        prefix_len=P, kv_keep=P + keep_pad,
+                        last_index=last_index)
 
             self._suffix_fns[key] = fn
         toks = np.zeros((1, S), np.int32)
         toks[0, :len(tokens)] = tokens
+        args = (self.params, jnp.asarray(toks), pk, pv,
+                jnp.asarray([len(tokens) - 1], jnp.int32))
         logits, kv = self._suffix_fns[key](
-            self.params, jnp.asarray(toks), pk, pv,
-            jnp.asarray([len(tokens) - 1], jnp.int32))
+            *args, *((state0,) if self.stateful else ()))
         n_new = min(keep_new, len(tokens))
         return logits, kv, n_new
 

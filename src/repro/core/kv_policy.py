@@ -53,12 +53,19 @@ class KVLifecycle:
     ``_run_suffix`` and ``PrefixCache.insert`` callers; every one of those
     sites now asks this object, so the policy is stated (and tested) once.
 
+    The rule covers recurrent state too: a hybrid's forward emits a
+    snapshot of its Mamba layers' state at every ``state_stride`` tokens of
+    the kept window, and the cache keeps those whose boundary lies inside
+    the blocks it takes; the rest is discarded with the suffix KV.
+
     All methods are pure shape/token arithmetic — safe to call under the
     engine lock and from routing probes.
     """
     block_size: int = 16
     kv_keep_tokens: int = 10**9             # suffix-discard threshold
     buckets: Tuple[int, ...] = (64, 128, 256, 512, 1024, 2048)
+    state_stride: int = 0                   # tokens between snapshots
+                                            # (0: no recurrent state)
 
     def keep(self, n_input: int) -> int:
         """Per-request KV budget in tokens (the kept prefix slice)."""
@@ -93,6 +100,21 @@ class KVLifecycle:
         raw per-request value would put every length in its own jit key."""
         return min(bucket(keep, self.buckets) if keep else 0, S)
 
+    def snapshot_slots(self, keep_pad: int) -> int:
+        """Snapshots a forward emits beside a fresh-KV window of
+        ``keep_pad`` tokens (its jit key's keep): one at each multiple of
+        the stride inside the window, which starts on a boundary, up to
+        the keep bound (none past it can ever be inserted)."""
+        if not self.state_stride:
+            return 0
+        return min(keep_pad, self.kv_keep_tokens) // self.state_stride
+
+    def snapshots_kept(self, n_insert: int) -> int:
+        """Of those, the snapshots the cache keeps when it takes
+        ``n_insert`` fresh tokens (whole blocks of real tokens, so no kept
+        snapshot lies past the prompt's last real token)."""
+        return n_insert // self.state_stride if self.state_stride else 0
+
     def insertable_tokens(self, keep: int, kv_from: int, n_new: int) -> int:
         """Tokens of fresh KV actually insertable after a forward that
         produced ``n_new`` kept tokens starting at offset ``kv_from``."""
@@ -110,6 +132,9 @@ class MemoryModel:
     # reuse each grouped-linear keeps input+output copies.
     output_prealloc: bool = True
     inplace: bool = True
+    # a hybrid's snapshot stride (tokens): each kept or cached window holds
+    # one snapshot of the Mamba layers' state per stride beside its KV
+    state_stride: int = 0
 
     # ---- per-token byte coefficients -------------------------------------
     @property
@@ -122,9 +147,20 @@ class MemoryModel:
 
     @property
     def kv_one_layer_per_token(self) -> float:
-        n = max(1, self.cfg.num_layers if self.cfg.family != "hybrid"
-                else self.cfg.num_layers // max(self.cfg.attn_every, 1))
-        return self.kv_all_per_token / n
+        return self.kv_all_per_token / max(1, self.cfg.n_attention_layers)
+
+    @property
+    def state_per_token(self) -> float:
+        """Snapshot bytes a kept or cached token carries: one snapshot of
+        the recurrent state per ``state_stride`` tokens."""
+        if not self.state_stride:
+            return 0.0
+        return self.cfg.state_bytes(BYTES) / self.state_stride
+
+    @property
+    def cache_per_token(self) -> float:
+        """Bytes the prefix cache pays per cached token: KV and state."""
+        return self.kv_all_per_token + self.state_per_token
 
     @property
     def mlp_int_per_token(self) -> float:
@@ -140,12 +176,20 @@ class MemoryModel:
 
     @property
     def attn_stream_per_token(self) -> float:
-        """Transient full-sequence q/k/v + residual streams for ONE layer."""
+        """Transient full-sequence q/k/v + residual streams for ONE layer
+        (for the published hybrid pattern, the larger of an attention
+        layer's and a Mamba layer's: its in-projection, float32 conv output
+        and float32 scan output)."""
         cfg = self.cfg
         if not cfg.has_attention:
             return 4.0 * cfg.d_model * BYTES
         qkv = (cfg.num_heads + 2 * cfg.num_kv_heads) * cfg.head_dim * BYTES
         resid = 4.0 * cfg.d_model * BYTES
+        if cfg.layer_types:
+            di, N = cfg.d_inner, cfg.ssm_state
+            mamba = ((2 * di + 2 * N + cfg.ssm_heads) * BYTES
+                     + (di + 2 * N) * 4 + di * 4)
+            return max(qkv, mamba) + resid
         return qkv + resid
 
     # ---- peak memory per technique ---------------------------------------
@@ -167,7 +211,7 @@ class MemoryModel:
         if technique == "discard":
             return W + S * act_full + S * self.kv_one_layer_per_token
         if technique == "hybrid":
-            kept = (min(S, kv_keep) * self.kv_all_per_token
+            kept = (min(S, kv_keep) * self.cache_per_token
                     if kv_keep is not None else 0.0)
             return (W + chunk * self.mlp_int_per_token
                     + S * self.attn_stream_per_token
@@ -192,13 +236,13 @@ class MemoryModel:
         base = self.peak_bytes(0, technique, chunk, k)
         slope = self.peak_bytes(1, technique, chunk, k) - base
         if kv_keep is not None and technique == "hybrid":
-            const = kv_keep * self.kv_all_per_token
+            const = kv_keep * self.cache_per_token
             if slope > 0 and base + const < budget:
                 s = int((budget - base - const) / slope)
                 if s > kv_keep:
                     return s
             # short-input branch: the kept slice still grows with S
-            slope += self.kv_all_per_token
+            slope += self.cache_per_token
         if base >= budget:
             return 0
         if slope <= 0:
@@ -213,9 +257,9 @@ class MemoryModel:
         same HBM yields a LARGER effective device cache (BENCH_offload)."""
         reserve = self.peak_bytes(mil, "hybrid", chunk, kv_keep=kv_keep)
         free = self.budget_bytes() - reserve
-        if free <= 0 or self.kv_all_per_token == 0:
+        if free <= 0 or self.cache_per_token == 0:
             return 0
-        return int(free / self.kv_all_per_token)
+        return int(free / self.cache_per_token)
 
     def mil_table(self, chunk: int = 2048, k: int = 2) -> Dict[str, int]:
         return {t: self.max_input_length(t, chunk, k)

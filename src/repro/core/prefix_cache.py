@@ -7,14 +7,17 @@ every scheduling step, so the per-call cost must be O(matched blocks) with an
 O(1) early exit on the first miss.
 
 Used in three places:
-  * the real CPU engine (payload = per-block KV arrays / SSM state checkpoints)
+  * the real engine (payload = per-block KV arrays; for a hybrid with
+    recurrent layers, the block at each reuse boundary also carries a
+    snapshot of their state, and occupies ``units`` > 1 of the capacity)
   * the discrete-event simulator (payload = None; pure accounting)
   * continuous JCT calibration (``match_len`` is the ``n_cached`` oracle)
 
 Invariants (property-tested):
   * a block is resident only if its parent is resident (chains are prefixes)
   * eviction removes LRU *leaf* blocks only, never pinned ones
-  * ``used_blocks <= capacity_blocks`` after any operation
+  * ``used_units <= capacity_blocks`` after any operation (a block occupies
+    one unit unless inserted with more; ``used_blocks`` counts blocks)
 """
 from __future__ import annotations
 
@@ -41,7 +44,9 @@ def token_chain(tokens: Sequence[int], block_size: int) -> Chain:
 class Block:
     hash: int
     parent: int
-    payload: Any = None        # KV slab / SSM state / None (sim)
+    payload: Any = None        # KV slab / KV + state snapshot / None (sim)
+    units: int = 1             # capacity it occupies (snapshot: its bytes
+                               # over one block of KV, plus the block)
     ref_count: int = 0         # pinned by running requests
     children: int = 0          # resident child blocks
     last_used: float = 0.0
@@ -53,6 +58,7 @@ class PrefixCache:
         self.capacity_blocks = capacity_blocks
         self.block_size = block_size
         self.blocks: Dict[int, Block] = {}
+        self.used_units = 0
         self._leaf_lru: "OrderedDict[int, None]" = OrderedDict()
         self.hits = 0
         self.misses = 0
@@ -84,6 +90,7 @@ class PrefixCache:
     def _remove(self, h: int):
         b = self.blocks.pop(h)
         assert b.children == 0 and b.ref_count == 0
+        self.used_units -= b.units
         self._set_leaf(h, False)
         if b.parent != ROOT and b.parent in self.blocks:
             parent = self.blocks[b.parent]
@@ -153,9 +160,11 @@ class PrefixCache:
             self.blocks[h].ref_count = max(0, self.blocks[h].ref_count - 1)
 
     def insert(self, chain: Chain, n_keep_tokens: int, now: float = 0.0,
-               payloads: Optional[List[Any]] = None) -> int:
+               payloads: Optional[List[Any]] = None,
+               units: Optional[Sequence[int]] = None) -> int:
         """Insert blocks covering the first ``n_keep_tokens`` tokens
         (PrefillOnly suffix-KV discard: caller passes the prefix budget).
+        ``units[i]``: capacity block i occupies if it is new (default 1).
         Evicts LRU leaves as needed; stops early if the cache cannot grow
         (everything pinned). Returns resident blocks of this chain."""
         n_blocks = min(len(chain), n_keep_tokens // self.block_size)
@@ -169,8 +178,9 @@ class PrefixCache:
             if h in self.blocks:
                 self._touch(h, now)
             else:
+                need = units[i] if units else 1
                 evicted_ok = True
-                while self.used_blocks >= self.capacity_blocks:
+                while self.used_units + need > self.capacity_blocks:
                     if not self._evict_one(exclude=own):
                         evicted_ok = False
                         break
@@ -178,7 +188,8 @@ class PrefixCache:
                     return resident
                 self.blocks[h] = Block(
                     hash=h, parent=parent, last_used=now,
-                    payload=payloads[i] if payloads else None)
+                    payload=payloads[i] if payloads else None, units=need)
+                self.used_units += need
                 self._set_leaf(h, True)
                 if parent != ROOT and parent in self.blocks:
                     p = self.blocks[parent]
@@ -193,6 +204,7 @@ class PrefixCache:
         total = self.hits + self.misses
         return {
             "used_blocks": self.used_blocks,
+            "used_units": self.used_units,
             "capacity_blocks": self.capacity_blocks,
             "evictions": self.evictions,
             "hit_rate": self.hits / total if total else 0.0,

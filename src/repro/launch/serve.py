@@ -278,7 +278,9 @@ def serve_trace(arch: str = "qwen1.5-0.5b",
             kv_keep = None
         ctrl = AdmissionController(max_input_tokens=max_input_tokens,
                                    memory_model=MemoryModel(
-                                       eng_cfg, any_eng.chip),
+                                       eng_cfg, any_eng.chip,
+                                       state_stride=getattr(
+                                           any_eng, "snap_every", 0)),
                                    kv_keep=kv_keep)
     # always-on request-lifecycle tracing: the ring bounds memory and the
     # per-event cost is one lock + list append (<3% on the packing

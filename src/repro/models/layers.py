@@ -363,7 +363,8 @@ def attention_defs(cfg: ModelConfig) -> Dict:
 
 def _qkv_project(p: Dict, x: jax.Array, cfg: ModelConfig,
                  positions: jax.Array, chunk: int):
-    """Token-wise QKV projection + RoPE, chunked under hybrid prefilling."""
+    """Token-wise QKV projection + RoPE (none under NoPE), chunked under
+    hybrid prefilling."""
     B, S, D = x.shape
     H, KV, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
 
@@ -380,8 +381,9 @@ def _qkv_project(p: Dict, x: jax.Array, cfg: ModelConfig,
     q = q.reshape(B, S, H, hd)
     k = k.reshape(B, S, KV, hd)
     v = v.reshape(B, S, KV, hd)
-    q = rope_apply(q, positions, cfg.rope_theta)
-    k = rope_apply(k, positions, cfg.rope_theta)
+    if cfg.position_embedding == "rope":
+        q = rope_apply(q, positions, cfg.rope_theta)
+        k = rope_apply(k, positions, cfg.rope_theta)
     # "attn_seq" (not "seq"): under sequence parallelism the residual
     # stream is seq-sharded but attention needs the full sequence — XLA
     # inserts the Megatron-SP all-gather here and the reduce-scatter after
@@ -440,15 +442,18 @@ def attention_prefill(p: Dict, x: jax.Array, cfg: ModelConfig, *,
         # (512, 1024) blocks a 1k packed batch is ONE tile and nothing skips
         out = blocked_attention(q, k, v, window=window,
                                 softcap=cfg.attn_softcap, seg_ids=seg_ids,
-                                q_block=128, kv_block=128)
+                                q_block=128, kv_block=128,
+                                head_scale=cfg.attn_head_scale)
     elif cp:
         out = _context_parallel_attention(
             q, k, v, window=window, softcap=cfg.attn_softcap, mesh=_CTX.mesh)
     elif cfg.packed_attention and window == 0:
-        out = packed_causal_attention(q, k, v, softcap=cfg.attn_softcap)
+        out = packed_causal_attention(q, k, v, softcap=cfg.attn_softcap,
+                                      head_scale=cfg.attn_head_scale)
     else:
         out = blocked_attention(q, k, v, window=window,
-                                softcap=cfg.attn_softcap)
+                                softcap=cfg.attn_softcap,
+                                head_scale=cfg.attn_head_scale)
     out = out.reshape(B, S, cfg.num_heads * cfg.head_dim)
     out = chunked_map(lambda oc: oc @ p["wo"], out, chunk)
     return constrain(out, ("batch", "seq", "d_model")), k, v

@@ -54,10 +54,16 @@ def _causal_conv(x: jax.Array, w: jax.Array, b: jax.Array) -> jax.Array:
 
 def ssd_scan(x: jax.Array, dA: jax.Array, Bm: jax.Array, Cm: jax.Array,
              dt: jax.Array, chunk: int,
-             h0: Optional[jax.Array] = None) -> Tuple[jax.Array, jax.Array]:
+             h0: Optional[jax.Array] = None,
+             snap_every: int = 0) -> Tuple[jax.Array, jax.Array]:
     """Chunked SSD. x: (B,S,H,P), dA: (B,S,H) (negative log-decay increments),
     Bm/Cm: (B,S,N), dt: (B,S,H). Returns (y: (B,S,H,P), final state (B,H,P,N)).
-    """
+
+    ``snap_every`` (a multiple of the chunk): return in place of the final
+    state the state after every ``snap_every`` tokens, (B, ceil(S /
+    snap_every), H, P, N), read from the carry of an outer scan over groups
+    of chunks, so only those states are ever stored. Chunks past the end
+    are zeros, which leave the state as it is."""
     B, S, H, P = x.shape
     N = Bm.shape[-1]
     Q = min(chunk, S)
@@ -69,6 +75,17 @@ def ssd_scan(x: jax.Array, dA: jax.Array, Bm: jax.Array, Cm: jax.Array,
         Cm = jnp.pad(Cm, ((0, 0), (0, pad), (0, 0)))
         dt = jnp.pad(dt, ((0, 0), (0, pad), (0, 0)))
     nc = x.shape[1] // Q
+
+    k = 0
+    if snap_every:
+        assert snap_every % Q == 0, (snap_every, Q)
+        k = snap_every // Q
+        extra = (-nc) % k
+        if extra:
+            grow = lambda a: jnp.pad(a, ((0, 0), (0, extra * Q))
+                                     + ((0, 0),) * (a.ndim - 2))
+            x, dA, Bm, Cm, dt = map(grow, (x, dA, Bm, Cm, dt))
+            nc += extra
 
     def to_chunks(a):
         return jnp.moveaxis(a.reshape(B, nc, Q, *a.shape[2:]), 1, 0)
@@ -98,19 +115,36 @@ def ssd_scan(x: jax.Array, dA: jax.Array, Bm: jax.Array, Cm: jax.Array,
                               dt_c * decay_end, x_c.astype(jnp.float32)))
         return h_new, y_off + y_diag
 
-    h_final, ys = jax.lax.scan(step, h0, xs)
+    if k:
+        def group(h, xs_g):
+            h, ys_g = jax.lax.scan(step, h, xs_g)
+            return h, (ys_g, h)
+
+        grouped = jax.tree_util.tree_map(
+            lambda a: a.reshape(nc // k, k, *a.shape[1:]), xs)
+        _, (ys, snaps) = jax.lax.scan(group, h0, grouped)
+        ys = ys.reshape(nc, *ys.shape[2:])
+        h_out = jnp.moveaxis(snaps, 0, 1)                    # (B, n, H, P, N)
+    else:
+        h_out, ys = jax.lax.scan(step, h0, xs)
     y = jnp.moveaxis(ys, 0, 1).reshape(B, nc * Q, H, P)
-    if pad:
+    if y.shape[1] != S:
         y = y[:, :S]
-    return y, h_final
+    return y, h_out
 
 
 def mamba_prefill(p: Dict, u: jax.Array, cfg: ModelConfig, *,
                   chunk: int = 0, h0: Optional[jax.Array] = None,
-                  conv0: Optional[jax.Array] = None
+                  conv0: Optional[jax.Array] = None, snap_every: int = 0,
+                  n_snap: int = 0
                   ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Full-sequence Mamba2 block. u: (B, S, D).
     Returns (out, final_ssm_state (B,H,P,N), final_conv_state (B,W-1,conv_dim)).
+
+    With ``n_snap`` > 0 the two states are instead the snapshots after
+    tokens ``snap_every * (j + 1)``, j < n_snap, of this call, stacked on
+    axis 1: (B, n_snap, H, P, N) and (B, n_snap, W-1, conv_dim). Resuming
+    from one (``h0``, ``conv0``) continues the sequence exactly there.
     """
     B, S, D = u.shape
     di, N, H, P = cfg.d_inner, cfg.ssm_state, cfg.ssm_heads, cfg.ssm_headdim
@@ -132,18 +166,27 @@ def mamba_prefill(p: Dict, u: jax.Array, cfg: ModelConfig, *,
         conv_out = _causal_conv(xBC, p["conv_w"], p["conv_b"])
         # left-pad so the returned conv state is always (B, W-1, Cd)
         xBC_ext = jnp.pad(xBC, ((0, 0), (W - 1, 0), (0, 0)))
-    conv_state = xBC_ext[:, -(W - 1):, :]
+    if n_snap:
+        # xBC_ext[t + i] is input t - (W-1) + i: the conv's window at t
+        conv_state = jnp.stack([xBC_ext[:, t:t + W - 1, :] for t in range(
+            snap_every, snap_every * (n_snap + 1), snap_every)], axis=1)
+    else:
+        conv_state = xBC_ext[:, -(W - 1):, :]
     xBC = jax.nn.silu(conv_out).astype(u.dtype)
     xr, Bm, Cm = jnp.split(xBC, [di, di + N], axis=-1)
     xh = xr.reshape(B, S, H, P)
     xh = constrain(xh, ("batch", "seq", "ssm_heads", None))
     dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"].astype(jnp.float32))
     A = -jnp.exp(p["A_log"].astype(jnp.float32))                 # (H,)
-    y, h_final = ssd_scan(xh, dt * A, Bm, Cm, dt, cfg.ssm_chunk, h0=h0)
+    y, h_final = ssd_scan(xh, dt * A, Bm, Cm, dt, cfg.ssm_chunk, h0=h0,
+                          snap_every=snap_every if n_snap else 0)
+    if n_snap:
+        h_final = h_final[:, :n_snap]
     y = y + (p["D"].astype(jnp.float32))[None, None, :, None] * xh.astype(jnp.float32)
     y = y.reshape(B, S, di)
     from repro.models.layers import rms_norm
-    y = rms_norm((y * jax.nn.silu(z.astype(jnp.float32))).astype(u.dtype), p["norm"])
+    y = rms_norm((y * jax.nn.silu(z.astype(jnp.float32))).astype(u.dtype),
+                 p["norm"], cfg.norm_eps)
     out = chunked_map(lambda yc: yc @ p["out"], y, chunk)
     return constrain(out, ("batch", "seq", "d_model")), h_final, conv_state.astype(u.dtype)
 

@@ -84,6 +84,9 @@ class BatchRecord:
     wall: float = 0.0            # measured forward wall time
     phases: Dict[str, float] = dataclasses.field(default_factory=dict)
                                  # host seconds per engine phase (Phase)
+    matched_tokens: int = 0      # prefix tokens whose blocks the cache held
+    resumed_tokens: int = 0      # prefix tokens reused, not recomputed (a
+                                 # hybrid resumes them from a state snapshot)
 
     @property
     def padding_waste(self) -> float:
