@@ -63,6 +63,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
+from repro.core.hybrid_prefill import skipped_slots
 from repro.core.jct import LinearProxyJCT, PackedShapeJCT, Sample
 from repro.core.kv_policy import KVLifecycle, bucket as _bucket
 from repro.core.offload import (HostKVStore, OffloadPolicy,
@@ -282,6 +283,7 @@ class PrefillOnlyEngine:
                                                # the current step (Phase)
         self._matched_tokens = 0               # this step's cached prefix
         self._resumed_tokens = 0               # and reused prefix tokens
+        self._skipped_tokens = 0               # and pad rows not computed
 
     # ---- profile run (paper §3.1) ------------------------------------------
     def profile(self, lengths: Sequence[int] = (64, 128, 256, 512)) -> float:
@@ -614,7 +616,7 @@ class PrefillOnlyEngine:
         if not self.queue:
             return None
         self._phases = {}
-        self._matched_tokens = self._resumed_tokens = 0
+        self._matched_tokens = self._resumed_tokens = self._skipped_tokens = 0
         with jax.profiler.TraceAnnotation("engine.step"):
             return self._step()
 
@@ -710,7 +712,8 @@ class PrefillOnlyEngine:
             compiled=self._step_compiled, predicted_jct=pred,
             wall=t_done - t0, phases=self._phases,
             matched_tokens=self._matched_tokens,
-            resumed_tokens=self._resumed_tokens)
+            resumed_tokens=self._resumed_tokens,
+            skipped_tokens=self._skipped_tokens)
         self.batch_records.append(rec)
         # compile steps are excluded from calibration for the same reason
         # they are excluded from the JCT fit: compile time is unbounded and
@@ -1086,6 +1089,8 @@ class PrefillOnlyEngine:
             self._fresh_fns[key] = fn
         toks = np.zeros((1, S), np.int32)
         toks[0, :len(tokens)] = tokens
+        self._skipped_tokens = skipped_slots(S, len(tokens),
+                                             self.cfg.hybrid_chunk)
         logits, kv = self._fresh_fns[key](
             self.params, jnp.asarray(toks),
             jnp.asarray([len(tokens) - 1], jnp.int32))
@@ -1388,6 +1393,8 @@ class PrefillOnlyEngine:
             self._suffix_fns[key] = fn
         toks = np.zeros((1, S), np.int32)
         toks[0, :len(tokens)] = tokens
+        self._skipped_tokens = skipped_slots(S, len(tokens),
+                                             self.cfg.hybrid_chunk)
         args = (self.params, jnp.asarray(toks), pk, pv,
                 jnp.asarray([len(tokens) - 1], jnp.int32))
         logits, kv = self._suffix_fns[key](

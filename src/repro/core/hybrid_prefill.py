@@ -40,12 +40,17 @@ def _pad_to_multiple(x: jax.Array, multiple: int, axis: int) -> Tuple[jax.Array,
 
 
 def chunked_map(fn: Callable[[jax.Array], jax.Array], x: jax.Array,
-                chunk: int, axis: int = 1) -> jax.Array:
+                chunk: int, axis: int = 1,
+                n_valid: Optional[jax.Array] = None) -> jax.Array:
     """Apply a token-wise ``fn`` over ``axis`` in chunks via ``lax.map``.
 
     ``fn`` maps (..., chunk, ...) -> (..., chunk, ...); it must be
     position-independent along ``axis`` (true for every linear/MLP/norm
     layer). Peak live intermediates inside ``fn`` are bounded by one chunk.
+
+    ``n_valid`` (traced scalar): rows at or past it are padding. A chunk
+    that starts there returns zeros and never runs ``fn``; a partly valid
+    chunk runs whole. ``skipped_slots`` is the host's count of those rows.
     """
     if chunk <= 0 or x.shape[axis] <= chunk:
         return fn(x)
@@ -53,9 +58,18 @@ def chunked_map(fn: Callable[[jax.Array], jax.Array], x: jax.Array,
     x, pad = _pad_to_multiple(x, chunk, axis)
     n = x.shape[axis] // chunk
 
+    def run(i):
+        return fn(jax.lax.dynamic_slice_in_dim(x, i * chunk, chunk, axis=axis))
+
     def body(i):
-        sl = jax.lax.dynamic_slice_in_dim(x, i * chunk, chunk, axis=axis)
-        return fn(sl)
+        if n_valid is None:
+            return run(i)
+        # the slice lives inside the branch, so a dead chunk copies nothing;
+        # zeros, not garbage: a pad row must stay finite, since a later PV
+        # product weighs it by 0 and 0 x NaN is NaN
+        out = jax.eval_shape(run, i)
+        return jax.lax.cond(i * chunk < n_valid, run,
+                            lambda _: jnp.zeros(out.shape, out.dtype), i)
 
     ys = jax.lax.map(body, jnp.arange(n))          # (n, ..., chunk, ...)
     ys = jnp.moveaxis(ys, 0, axis)                 # (..., n, chunk, ...)
@@ -64,6 +78,20 @@ def chunked_map(fn: Callable[[jax.Array], jax.Array], x: jax.Array,
     if pad:
         ys = jax.lax.slice_in_dim(ys, 0, new_shape[axis] - pad, axis=axis)
     return ys
+
+
+def valid_rows(last_index: Optional[jax.Array]) -> Optional[jax.Array]:
+    """Real rows of a padded solo forward, through its last scored token:
+    the ``n_valid`` of ``chunked_map`` (None without ``last_index``)."""
+    return None if last_index is None else jnp.max(last_index) + 1
+
+
+def skipped_slots(S: int, n_valid: int, chunk: int) -> int:
+    """Rows of an ``S``-row forward that ``chunked_map(..., n_valid=)``
+    skips: those of the chunks that start at or past ``n_valid``."""
+    if chunk <= 0 or S <= chunk:
+        return 0
+    return max(0, S - -(-n_valid // chunk) * chunk)
 
 
 def chunked_softmax_xent(hidden: jax.Array, w_head: jax.Array,

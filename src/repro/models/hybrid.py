@@ -26,7 +26,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.core.hybrid_prefill import (chunked_map, chunked_softmax_xent,
-                                       last_token_logits)
+                                       last_token_logits, valid_rows)
 from repro.models import layers as L
 from repro.models.mamba2 import mamba_defs, mamba_prefill, mamba_decode
 from repro.models.transformer import stack_defs, head_weight
@@ -256,7 +256,8 @@ def _pattern_defs(cfg: ModelConfig) -> Dict:
 def _pattern_forward(params: Dict, cfg: ModelConfig, tokens: jax.Array, *,
                      prefix_len: int = 0, prefix_kv: Optional[Dict] = None,
                      state0: Optional[Dict] = None, kv_keep: int = 0,
-                     snap_every: int = 0, n_snap: int = 0
+                     snap_every: int = 0, n_snap: int = 0,
+                     n_valid: Optional[jax.Array] = None
                      ) -> Tuple[jax.Array, Dict]:
     """Final-normed hidden (B, S, D) of ``tokens`` at positions
     ``prefix_len + [0, S)``, and {"k", "v": (attention layers, B, keep, KV,
@@ -267,7 +268,12 @@ def _pattern_forward(params: Dict, cfg: ModelConfig, tokens: jax.Array, *,
     prefix_len, KV, hd)} and ``state0`` {"ssm": (Mamba layers, B, H, P, N),
     "conv": (Mamba layers, B, W-1, conv_dim)}, the states after the prefix.
     The layers run as a scan over periods of the pattern; inside a period
-    each run of Mamba layers is a scan of its own."""
+    each run of Mamba layers is a scan of its own.
+
+    ``n_valid`` (traced scalar): tokens at or past it are padding. The
+    attention layers and the MLPs skip what starts past it, as the dense
+    ``forward_full`` does; the Mamba mixers run every row, and no state
+    past the prompt is kept (``KVLifecycle.snapshots_kept``)."""
     dtype = jnp.dtype(cfg.dtype)
     rm = jnp.asarray(cfg.residual_multiplier, dtype)
     eps, chunk = cfg.norm_eps, cfg.hybrid_chunk
@@ -297,7 +303,7 @@ def _pattern_forward(params: Dict, cfg: ModelConfig, tokens: jax.Array, *,
 
     def mlp(x, lp):
         return x + rm * L.mlp_apply(lp["mlp"], L.rms_norm(x, lp["ln2"], eps),
-                                    chunk=chunk)
+                                    chunk=chunk, n_valid=n_valid)
 
     def mamba_layer(x, inp):
         lp, h0, c0 = inp
@@ -311,15 +317,19 @@ def _pattern_forward(params: Dict, cfg: ModelConfig, tokens: jax.Array, *,
         h = L.rms_norm(x, lp["ln1"], eps)
         if pk is None:
             out, k, v = L.attention_prefill(lp["attn"], h, cfg,
-                                            positions=positions, chunk=chunk)
+                                            positions=positions, chunk=chunk,
+                                            n_valid=n_valid)
         else:
-            q, k, v = L._qkv_project(lp["attn"], h, cfg, positions, chunk)
+            q, k, v = L._qkv_project(lp["attn"], h, cfg, positions, chunk,
+                                     n_valid)
             out = L.blocked_attention(
                 q, jnp.concatenate([pk.astype(k.dtype), k], axis=1),
                 jnp.concatenate([pv.astype(v.dtype), v], axis=1),
-                q_offset=prefix_len, head_scale=cfg.attn_head_scale)
+                q_offset=prefix_len, head_scale=cfg.attn_head_scale,
+                q_valid=n_valid)
             out = out.reshape(B, S, cfg.num_heads * cfg.head_dim)
-            out = chunked_map(lambda oc: oc @ lp["attn"]["wo"], out, chunk)
+            out = chunked_map(lambda oc: oc @ lp["attn"]["wo"], out, chunk,
+                              n_valid=n_valid)
         x = mlp(x + rm * out, lp)
         kv = ((k[:, :keep].astype(dtype), v[:, :keep].astype(dtype))
               if keep else None)
@@ -371,10 +381,12 @@ def pattern_prefill(params: Dict, cfg: ModelConfig, tokens: jax.Array, *,
     ``_pattern_forward`` returns it). Fresh when ``prefix_len`` is 0;
     otherwise ``tokens`` is the suffix after a cached prefix of
     ``prefix_len`` tokens, a multiple of ``snap_every`` when snapshots are
-    asked for, so that theirs fall on the same boundaries."""
+    asked for, so that theirs fall on the same boundaries. Tokens past
+    ``last_index`` are padding, skipped where ``_pattern_forward`` can."""
     hidden, aux = _pattern_forward(
         params, cfg, tokens, prefix_len=prefix_len, prefix_kv=prefix_kv,
-        state0=state0, kv_keep=kv_keep, snap_every=snap_every, n_snap=n_snap)
+        state0=state0, kv_keep=kv_keep, snap_every=snap_every, n_snap=n_snap,
+        n_valid=valid_rows(last_index))
     logits = last_token_logits(hidden, head_weight(params, cfg),
                                last_index=last_index)
     return logits / cfg.logits_scaling, aux

@@ -75,7 +75,8 @@ def blocked_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                       seg_ids: Optional[jax.Array] = None,
                       seg_ids_k: Optional[jax.Array] = None,
                       pos_q: Optional[jax.Array] = None,
-                      pos_k: Optional[jax.Array] = None) -> jax.Array:
+                      pos_k: Optional[jax.Array] = None,
+                      q_valid: Optional[jax.Array] = None) -> jax.Array:
     """Flash-style attention. q: (B,Sq,H,d), k/v: (B,Skv,KV,d) -> (B,Sq,H,d).
 
     Online-softmax over KV blocks (lax.scan) x lax.map over Q blocks: the HLO
@@ -95,6 +96,11 @@ def blocked_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     prefix_len + local offset; its prefix tokens at [0, prefix_len)), and the
     causal tile skip becomes a dynamic min/max position range test, so a
     query block never computes another segment's prefix tiles.
+
+    ``q_valid`` (traced scalar): only the first ``q_valid`` query rows are
+    real, the rest padding after the prompt. A query tile that starts at or
+    past it runs no kv scan and returns zeros, and keys at or past
+    ``q_offset + q_valid`` are masked, so kv tiles there are skipped too.
     """
     B, Sq, H, d = q.shape
     _, Skv, KV, _ = k.shape
@@ -132,6 +138,10 @@ def blocked_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     nq, nk = q.shape[1] // qb, k.shape[1] // kb
     qg = q.reshape(B, nq, qb, KV, G, d)
     kv_len = jnp.asarray(Skv)  # mask out k-padding
+    if q_valid is not None:
+        # keys past the prompt: causality already hides them from every
+        # real query; the mask keeps pad rows off them as well
+        kv_len = jnp.minimum(kv_len, q_offset + q_valid)
 
     def one_q_block(i):
         q_blk = qg[:, i].astype(jnp.float32) * scale      # (B,qb,KV,G,d)
@@ -213,12 +223,22 @@ def blocked_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                 live = live & (jnp.max(sk_blk) >= 0)
             return jax.lax.cond(live, compute, lambda c: c, carry), None
 
-        m0 = jnp.full((B, KV, G, qb), NEG_INF, jnp.float32)
-        l0 = jnp.zeros((B, KV, G, qb), jnp.float32)
-        a0 = jnp.zeros((B, KV, G, qb, d), jnp.float32)
-        (m, l, acc), _ = jax.lax.scan(kv_step, (m0, l0, a0), jnp.arange(nk))
-        out = acc / jnp.maximum(l, 1e-30)[..., None]       # (B,KV,G,qb,d)
-        return out.transpose(0, 3, 1, 2, 4)                # (B,qb,KV,G,d)
+        def scan_tile(_):
+            m0 = jnp.full((B, KV, G, qb), NEG_INF, jnp.float32)
+            l0 = jnp.zeros((B, KV, G, qb), jnp.float32)
+            a0 = jnp.zeros((B, KV, G, qb, d), jnp.float32)
+            (m, l, acc), _ = jax.lax.scan(kv_step, (m0, l0, a0),
+                                          jnp.arange(nk))
+            out = acc / jnp.maximum(l, 1e-30)[..., None]   # (B,KV,G,qb,d)
+            return out.transpose(0, 3, 1, 2, 4)            # (B,qb,KV,G,d)
+
+        if q_valid is None:
+            return scan_tile(None)
+        # one branch around the whole kv scan: a tile of padding alone
+        # costs nothing, and its zero rows stay finite
+        return jax.lax.cond(
+            i * qb < q_valid, scan_tile,
+            lambda _: jnp.zeros((B, qb, KV, G, d), jnp.float32), None)
 
     outs = jax.lax.map(one_q_block, jnp.arange(nq))        # (nq,B,qb,KV,G,d)
     out = jnp.moveaxis(outs, 0, 1).reshape(B, nq * qb, H, d)
@@ -362,9 +382,10 @@ def attention_defs(cfg: ModelConfig) -> Dict:
 
 
 def _qkv_project(p: Dict, x: jax.Array, cfg: ModelConfig,
-                 positions: jax.Array, chunk: int):
+                 positions: jax.Array, chunk: int,
+                 n_valid: Optional[jax.Array] = None):
     """Token-wise QKV projection + RoPE (none under NoPE), chunked under
-    hybrid prefilling."""
+    hybrid prefilling; chunks past ``n_valid`` real rows give zeros."""
     B, S, D = x.shape
     H, KV, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
 
@@ -376,7 +397,7 @@ def _qkv_project(p: Dict, x: jax.Array, cfg: ModelConfig,
             q, k, v = q + p["bq"], k + p["bk"], v + p["bv"]
         return jnp.concatenate([q, k, v], axis=-1)
 
-    qkv = chunked_map(proj, x, chunk)
+    qkv = chunked_map(proj, x, chunk, n_valid=n_valid)
     q, k, v = jnp.split(qkv, [H * hd, (H + KV) * hd], axis=-1)
     q = q.reshape(B, S, H, hd)
     k = k.reshape(B, S, KV, hd)
@@ -422,17 +443,20 @@ def _context_parallel_attention(q, k, v, *, window: int, softcap: float,
 
 def attention_prefill(p: Dict, x: jax.Array, cfg: ModelConfig, *,
                       positions: jax.Array, window: int = 0,
-                      chunk: int = 0, seg_ids: Optional[jax.Array] = None
+                      chunk: int = 0, seg_ids: Optional[jax.Array] = None,
+                      n_valid: Optional[jax.Array] = None
                       ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Full-sequence attention. Returns (out, k, v) — the caller decides how
     much of (k, v) to keep (suffix KV discard happens there).
 
     ``seg_ids`` selects the prepacked path: segment-restricted blocked
     attention (single instance — the context-parallel and tile-packing
-    schedules assume one contiguous causal sequence)."""
+    schedules assume one contiguous causal sequence). ``n_valid`` (traced
+    scalar): rows at or past it are padding after the prompt, which the
+    token-wise chunks and the blocked attention's query tiles skip."""
     from repro.runtime.sharding import _CTX
     B, S, D = x.shape
-    q, k, v = _qkv_project(p, x, cfg, positions, chunk)
+    q, k, v = _qkv_project(p, x, cfg, positions, chunk, n_valid)
     rules = _CTX.rules or {}
     cp = (_CTX.mesh is not None and rules.get("attn_seq") == "model"
           and S % _CTX.mesh.shape.get("model", 1) == 0)
@@ -453,9 +477,10 @@ def attention_prefill(p: Dict, x: jax.Array, cfg: ModelConfig, *,
     else:
         out = blocked_attention(q, k, v, window=window,
                                 softcap=cfg.attn_softcap,
-                                head_scale=cfg.attn_head_scale)
+                                head_scale=cfg.attn_head_scale,
+                                q_valid=n_valid)
     out = out.reshape(B, S, cfg.num_heads * cfg.head_dim)
-    out = chunked_map(lambda oc: oc @ p["wo"], out, chunk)
+    out = chunked_map(lambda oc: oc @ p["wo"], out, chunk, n_valid=n_valid)
     return constrain(out, ("batch", "seq", "d_model")), k, v
 
 
@@ -502,16 +527,18 @@ def mlp_defs(d_model: int, d_ff: int) -> Dict:
     }
 
 
-def mlp_apply(p: Dict, x: jax.Array, chunk: int = 0) -> jax.Array:
+def mlp_apply(p: Dict, x: jax.Array, chunk: int = 0,
+              n_valid: Optional[jax.Array] = None) -> jax.Array:
     """SwiGLU MLP; the (tokens, d_ff) intermediate is the paper's memory
-    villain — chunked under hybrid prefilling."""
+    villain — chunked under hybrid prefilling, skipping chunks past
+    ``n_valid`` real rows."""
 
     def f(xc):
         g = xc @ p["w_gate"]
         u = xc @ p["w_up"]
         return (jax.nn.silu(g.astype(jnp.float32)).astype(xc.dtype) * u) @ p["w_down"]
 
-    out = chunked_map(f, x, chunk)
+    out = chunked_map(f, x, chunk, n_valid=n_valid)
     return constrain(out, ("batch", "seq", "d_model"))
 
 

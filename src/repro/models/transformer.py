@@ -15,7 +15,7 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.core.hybrid_prefill import (chunked_softmax_xent,
                                        last_token_logits,
-                                       packed_last_logits)
+                                       packed_last_logits, valid_rows)
 from repro.models import layers as L
 from repro.models.moe import moe_defs, moe_apply
 from repro.runtime.sharding import pdef, ParamDef, is_paramdef_leaf
@@ -90,20 +90,21 @@ def _cast_block(bp: Dict, dtype) -> Dict:
 
 def _block_full(bp: Dict, x: jax.Array, cfg: ModelConfig, *,
                 positions: jax.Array, window: int, chunk: int,
-                num_shards: int, seg_ids: Optional[jax.Array] = None
+                num_shards: int, seg_ids: Optional[jax.Array] = None,
+                n_valid: Optional[jax.Array] = None
                 ) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array]]:
     bp = _cast_block(bp, cfg.dtype)
     h = L.rms_norm(x, bp["ln1"])
     attn, k, v = L.attention_prefill(bp["attn"], h, cfg, positions=positions,
                                      window=window, chunk=chunk,
-                                     seg_ids=seg_ids)
+                                     seg_ids=seg_ids, n_valid=n_valid)
     x = x + attn
     h = L.rms_norm(x, bp["ln2"])
     if cfg.is_moe:
         m = moe_apply(bp["moe"], h, cfg, num_shards=num_shards,
                       hybrid_chunk=chunk)
     else:
-        m = L.mlp_apply(bp["mlp"], h, chunk=chunk)
+        m = L.mlp_apply(bp["mlp"], h, chunk=chunk, n_valid=n_valid)
     return x + m, (k, v)
 
 
@@ -114,7 +115,8 @@ def forward_full(params: Dict, cfg: ModelConfig, *,
                  remat: bool = False,
                  positions: Optional[jax.Array] = None,
                  seg_ids: Optional[jax.Array] = None,
-                 kv_indices: Optional[jax.Array] = None
+                 kv_indices: Optional[jax.Array] = None,
+                 n_valid: Optional[jax.Array] = None
                  ) -> Tuple[jax.Array, Optional[Dict]]:
     """Returns (final-normed hidden (B,S,D), kv tree or None).
 
@@ -136,6 +138,11 @@ def forward_full(params: Dict, cfg: ModelConfig, *,
     instead of a prefix slice, so per-segment keep windows scattered through
     the packed sequence cost K stacked tokens, not S (suffix discard keeps
     its memory bound under packing). Overrides ``kv_keep`` when given.
+
+    ``n_valid`` (traced scalar): only the first ``n_valid`` tokens are real,
+    the rest padding after the prompt. The blocked attention's query tiles
+    and the token-wise chunks that start past it are not computed, so the
+    hidden rows and KV there are not the padding's: zeros or stale values.
     """
     dtype = jnp.dtype(cfg.dtype)
     if embeds is None:
@@ -155,7 +162,8 @@ def forward_full(params: Dict, cfg: ModelConfig, *,
     def run_block(x, bp, window):
         x, (k, v) = _block_full(bp, x, cfg, positions=positions,
                                 window=window, chunk=chunk,
-                                num_shards=num_shards, seg_ids=seg_ids)
+                                num_shards=num_shards, seg_ids=seg_ids,
+                                n_valid=n_valid)
         # keep the prefix KV in compute dtype — rope's f32 internals must
         # not leak into the (layers, B, keep, KV, hd) scan output stack
         if kv_indices is not None:
@@ -245,10 +253,15 @@ def prefill(params: Dict, cfg: ModelConfig, batch: Dict, *,
             kv_keep: int = 0, num_shards: int = 1,
             last_index: Optional[jax.Array] = None
             ) -> Tuple[jax.Array, Optional[Dict]]:
-    """PrefillOnly serving prefill: (last-token logits (B, V), prefix KV)."""
+    """PrefillOnly serving prefill: (last-token logits (B, V), prefix KV).
+
+    With ``last_index``, tokens past it are padding: the forward computes
+    no query tile and no token-wise chunk that starts past it
+    (``forward_full``'s ``n_valid``), and their KV is not theirs."""
     hidden, kv = forward_full(params, cfg, tokens=batch.get("tokens"),
                               embeds=batch.get("embeds"), kv_keep=kv_keep,
-                              num_shards=num_shards)
+                              num_shards=num_shards,
+                              n_valid=valid_rows(last_index))
     logits = last_token_logits(hidden, head_weight(params, cfg),
                                last_index=last_index,
                                final_softcap=cfg.final_softcap)
@@ -300,6 +313,7 @@ def prefill_with_prefix(params: Dict, cfg: ModelConfig, batch: Dict,
     logits + the suffix KV to extend the cache with (up to ``kv_keep`` total
     tokens — suffix discard). Dense/vlm/audio/moe families, full attention
     (window archs take the full-attention path here; engine demos are dense).
+    Suffix tokens past ``last_index`` are padding, skipped as in ``prefill``.
     """
     dtype = jnp.dtype(cfg.dtype)
     if batch.get("embeds") is None:
@@ -311,15 +325,18 @@ def prefill_with_prefix(params: Dict, cfg: ModelConfig, batch: Dict,
                                  (B, S))
     chunk = cfg.hybrid_chunk
     keep_new = max(0, min(kv_keep, prefix_len + S) - prefix_len)
+    n_valid = valid_rows(last_index)
 
     def body(x, xs):
         bp, pk, pv = xs
         h = L.rms_norm(x, bp["ln1"])
-        q, k, v = L._qkv_project(bp["attn"], h, cfg, positions, chunk)
+        q, k, v = L._qkv_project(bp["attn"], h, cfg, positions, chunk,
+                                 n_valid)
         k_full = jnp.concatenate([pk.astype(k.dtype), k], axis=1)
         v_full = jnp.concatenate([pv.astype(v.dtype), v], axis=1)
         out = L.blocked_attention(q, k_full, v_full, window=cfg.sliding_window,
-                                  softcap=cfg.attn_softcap, q_offset=prefix_len)
+                                  softcap=cfg.attn_softcap, q_offset=prefix_len,
+                                  q_valid=n_valid)
         out = out.reshape(B, S, cfg.num_heads * cfg.head_dim)
         out = out @ bp["attn"]["wo"]
         x = x + out
@@ -328,7 +345,7 @@ def prefill_with_prefix(params: Dict, cfg: ModelConfig, batch: Dict,
             m = moe_apply(bp["moe"], h, cfg, num_shards=num_shards,
                           hybrid_chunk=chunk)
         else:
-            m = L.mlp_apply(bp["mlp"], h, chunk=chunk)
+            m = L.mlp_apply(bp["mlp"], h, chunk=chunk, n_valid=n_valid)
         return x + m, (k[:, :keep_new], v[:, :keep_new])
 
     x, kvs = jax.lax.scan(body, x, (params["blocks"], prefix_kv["k"],

@@ -87,6 +87,8 @@ class BatchRecord:
     matched_tokens: int = 0      # prefix tokens whose blocks the cache held
     resumed_tokens: int = 0      # prefix tokens reused, not recomputed (a
                                  # hybrid resumes them from a state snapshot)
+    skipped_tokens: int = 0      # forward slots past the prompt whose
+                                 # token-wise chunks were not computed
 
     @property
     def padding_waste(self) -> float:
@@ -494,7 +496,8 @@ class SpanTracer:
                          ("step", "n_requests", "req_ids", "computed_tokens",
                           "padded_tokens", "padding_waste", "S", "Nb",
                           "smax", "pmax", "K", "jit_path", "jit_key",
-                          "compiled", "predicted_jct", "wall", "phases")}})
+                          "compiled", "predicted_jct", "wall", "phases",
+                          "skipped_tokens")}})
         return {"traceEvents": events, "displayTimeUnit": "ms"}
 
     def stats(self) -> Dict:
